@@ -2,10 +2,10 @@
 asynchronously by the middleware (the paper's §6.1 experiment, with jitted
 model steps instead of `stress`).
 
-Task types (all real JAX work on reduced configs):
+Task types (all real JAX work on a reduced qwen2; see repro/launch/ddmd.py):
   Simulation   autoregressive decode rollout (MD-like trajectory producer)
   Aggregation  reduction over produced trajectories (CPU-ish)
-  Training     train_step()s of a reduced qwen2 on the aggregated tokens
+  Training     train_step()s on the aggregated tokens
   Inference    batched prefill scoring candidate sequences
 
 The RealExecutor enforces the same (cpus, gpus) accounting as the paper's
@@ -17,86 +17,26 @@ Run:  PYTHONPATH=src python examples/deepdrivemd_async.py
 
 import time
 
-import jax
-import jax.numpy as jnp
-
 from repro.configs import get_config
-from repro.core import RealExecutor, deepdrivemd_dag, relative_improvement
-from repro.core.workflow import ddmd_sequential_stage_groups
+from repro.core import RealExecutor, RunConfig, relative_improvement
+from repro.core.resources import Allocation, NodeSpec, PoolSpec
+from repro.launch import ddmd
 from repro.models.api import build_model
-from repro.models.params import init_params
-from repro.runtime import TrainOptions
-from repro.runtime.steps import build_decode_step, build_prefill_step, \
-    build_train_step, make_train_state
-
-# -- build the real payloads (reduced config; jitted once, reused) ----------
-CFG = get_config("qwen2-0.5b").reduced()
-MODEL = build_model(CFG)
-PARAMS = init_params(MODEL.specs(), jax.random.PRNGKey(0))
-STATE = make_train_state(MODEL, jax.random.PRNGKey(1))
-TRAIN_STEP, _ = build_train_step(MODEL, opts=TrainOptions(total_steps=100))
-PREFILL, _ = build_prefill_step(MODEL)
-DECODE, _ = build_decode_step(MODEL, batch=2, s_max=64)
-
-
-def simulation_payload(i: int):
-    """Decode rollout: 8 tokens for 2 'trajectories'."""
-    cache = init_params(MODEL.cache_specs(2, 64), jax.random.PRNGKey(i))
-    tok = jnp.full((2, 1), 3, jnp.int32)
-    for t in range(8):
-        nxt, _, cache = DECODE(PARAMS, cache, tok,
-                               jnp.full((2,), t, jnp.int32))
-        tok = nxt[:, None]
-    return jax.block_until_ready(tok)
-
-
-def aggregation_payload(i: int):
-    x = jax.random.normal(jax.random.PRNGKey(i), (1 << 16,))
-    return jax.block_until_ready(jnp.sort(x)[::64].sum())
-
-
-def training_payload(i: int):
-    global STATE
-    batch = MODEL.make_batch(jax.random.PRNGKey(100 + i), batch=2, seq=32)
-    STATE, metrics = TRAIN_STEP(STATE, batch)
-    return jax.block_until_ready(metrics["loss"])
-
-
-def inference_payload(i: int):
-    batch = MODEL.make_batch(jax.random.PRNGKey(200 + i), batch=2, seq=32,
-                             mode="prefill")
-    return jax.block_until_ready(PREFILL(PARAMS, batch))
-
-
-#: scaled-down task counts/durations (laptop-scale validation, §7 analogue)
-TABLE = dict(
-    simulation=dict(cpus=1, gpus=1, n=6, tx=0.0),
-    aggregation=dict(cpus=2, gpus=0, n=3, tx=0.0),
-    training=dict(cpus=1, gpus=1, n=1, tx=0.0),
-    inference=dict(cpus=1, gpus=1, n=6, tx=0.0),
-)
-
-PAYLOADS = dict(simulation=simulation_payload, aggregation=aggregation_payload,
-                training=training_payload, inference=inference_payload)
 
 
 def main():
-    # warm the jit caches so the comparison measures orchestration
-    for fn in PAYLOADS.values():
+    payloads = ddmd.DDMDPayloads(build_model(
+        get_config("qwen2-0.5b").reduced()))
+    # warm the remaining jit caches so the comparison measures orchestration
+    for fn in payloads.as_dict().values():
         fn(0)
 
-    from repro.core.resources import Allocation, NodeSpec, PoolSpec
-    pool = PoolSpec("laptop", num_nodes=1, node=NodeSpec(cpus=8, gpus=4))
-    dag = deepdrivemd_dag(3, table=TABLE, payloads=PAYLOADS)
-
-    ex = RealExecutor(pool, launch_latency=0.002)
     t0 = time.perf_counter()
-    seq = ex.run(dag, "sequential",
-                 sequential_stage_groups=ddmd_sequential_stage_groups(3))
+    seq, _ = ddmd.run(payloads, "sequential")
     t_seq = time.perf_counter() - t0
 
     t0 = time.perf_counter()
-    asy = ex.run(dag, "async")
+    asy, _ = ddmd.run(payloads, "async")
     t_async = time.perf_counter() - t0
 
     i = relative_improvement(t_seq, t_async)
@@ -113,7 +53,8 @@ def main():
         PoolSpec("cpu", num_nodes=1, node=NodeSpec(cpus=8, gpus=0)),
     ))
     het = RealExecutor(hetero, launch_latency=0.002).run(
-        dag, "async", scheduling="gpu_bestfit")
+        ddmd.dag(payloads), "async",
+        config=RunConfig(scheduling="gpu_bestfit"))
     print(f"hybrid pools (gpu_bestfit): {het.per_pool_task_counts()}")
     return i
 

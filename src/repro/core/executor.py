@@ -71,6 +71,16 @@ class ExecResult(RunResult):
     clock (wall / ``tx_scale``), commensurate with the simulator's."""
 
 
+class PayloadError(RuntimeError):
+    """A task payload raised: the run stops at the first such exception,
+    which is chained as ``__cause__``; ``task`` is its ``(set, index)``."""
+
+    def __init__(self, name: str, i: int, err: BaseException):
+        super().__init__(f"payload of task {name}[{i}] raised "
+                         f"{type(err).__name__}: {err}")
+        self.task = (name, i)
+
+
 class RealExecutor:
     """Executes a task-set DG with real concurrency on the local host."""
 
@@ -209,6 +219,9 @@ class RealExecutor:
         #: duplicates promoted to primary (their primary's node died):
         #: the spec worker completes the task as the primary instead
         promoted_keys: set[tuple[str, int]] = set()
+        #: the first payload exception (set, index, exception): it ends
+        #: the run and is re-raised from ``run()``
+        failure: list[tuple[str, int, BaseException]] = []
         t0 = time.perf_counter()
 
         def preemptible_sleep(name: str, i: int, my_gen: int,
@@ -226,7 +239,7 @@ class RealExecutor:
             g_of = spec_gen if spec else gen
             with cv:
                 while True:
-                    if (my_gen != g_of.get((name, i), 0)
+                    if (failure or my_gen != g_of.get((name, i), 0)
                             or (name, i) in engine.finished):
                         return False
                     remaining = deadline - time.perf_counter()
@@ -260,7 +273,7 @@ class RealExecutor:
 
         def valid(name: str, i: int, my_gen: int, spec: bool) -> bool:
             """Is this attempt still the live one? (caller holds ``cv``)"""
-            if (name, i) in engine.finished:
+            if failure or (name, i) in engine.finished:
                 return False
             g_of = spec_gen if spec else gen
             return my_gen == g_of.get((name, i), 0)
@@ -361,6 +374,18 @@ class RealExecutor:
                                           workflow=wf_of.get(name, "")))
                 cv.notify_all()
 
+        def attempt(name: str, i: int, *args) -> None:
+            """Worker entry: ``body``, with its exception also handed to
+            the dispatcher, which would otherwise wait for a completion
+            that never comes."""
+            try:
+                body(name, i, *args)
+            except BaseException as err:
+                with cv:
+                    failure.append((name, i, err))
+                    cv.notify_all()
+                raise
+
         # the watchdog needs a mitigation that can actually fire: migration
         # needs a second pool; speculation only needs a free slot, so it
         # keeps the watchdog alive even on single-pool allocations.
@@ -385,7 +410,8 @@ class RealExecutor:
         last_stamp = None
         with ThreadPoolExecutor(max_workers=self.max_workers) as ex:
             with cv:
-                while not engine.done() or stream_pending():
+                while (not engine.done() or stream_pending()) \
+                        and not failure:
                     # backfill: start everything ready that fits.  The
                     # pass runs on the modelled clock (see observe) so
                     # campaign arrivals gate on the same time base as the
@@ -424,17 +450,17 @@ class RealExecutor:
                     batch = engine.startable(now)
                     for name, i, pool_idx in batch:
                         if faults is None:
-                            ex.submit(body, name, i, pool_idx, 0)
+                            ex.submit(attempt, name, i, pool_idx, 0)
                             continue
                         d = engine.dispatch_duration(
                             name, i, durations[(name, i)], pool_idx)
                         frac = schedule.attempt_failure(
                             name, i, engine.attempt_number(name, i))
-                        ex.submit(body, name, i, pool_idx,
+                        ex.submit(attempt, name, i, pool_idx,
                                   gen.get((name, i), 0), 0.0, d, False,
                                   frac)
                     if (not engine.done() or stream_pending()) \
-                            and not batch:
+                            and not batch and not failure:
                         # with mitigation on, the wait doubles as the
                         # straggler watchdog cadence; a pending campaign
                         # arrival (or fault/recovery/stream/lease event)
@@ -474,14 +500,14 @@ class RealExecutor:
                                 # straggler clock pauses until the re-run's
                                 # worker stamps its own start
                                 started.pop((sn, si), None)
-                                ex.submit(body, sn, si, dst, gen[(sn, si)],
+                                ex.submit(attempt, sn, si, dst, gen[(sn, si)],
                                           cost,
                                           engine.tx_estimate(sn, pool=dst))
                                 # wake preempted synthetic sleeps so they
                                 # release their worker slots promptly
                                 cv.notify_all()
                             else:  # speculate: a duplicate races the task
-                                ex.submit(body, sn, si, dst,
+                                ex.submit(attempt, sn, si, dst,
                                           spec_gen.get((sn, si), 0), cost,
                                           engine.tx_estimate(sn, pool=dst),
                                           True)
@@ -493,7 +519,7 @@ class RealExecutor:
                             if rep is None:
                                 continue
                             dst, cost = rep
-                            ex.submit(body, rn2, ri2, dst,
+                            ex.submit(attempt, rn2, ri2, dst,
                                       spec_gen.get((rn2, ri2), 0), cost,
                                       engine.tx_estimate(rn2, pool=dst),
                                       True)
@@ -507,6 +533,13 @@ class RealExecutor:
                             or engine.predict_stamp() != last_stamp):
                         engine.repredict(now, modelled)
                         last_stamp = engine.predict_stamp()
+            if failure:
+                # queued attempts never start; running ones see
+                # ``failure`` at their next check and return
+                ex.shutdown(wait=False, cancel_futures=True)
+        if failure:
+            name, i, err = failure[0]
+            raise PayloadError(name, i, err) from err
 
         makespan = max((r.end for r in records), default=0.0)
         if stream is not None:

@@ -27,10 +27,15 @@ def decode_attention_jnp(q, cache_k, cache_v, valid, *, pos=None,
                                 window=window, chunk=chunk, rolling=rolling)
 
 
+def default_impl() -> str:
+    """What ``impl="auto"`` runs: pallas on TPU, jnp elsewhere."""
+    return "pallas" if jax.default_backend() == "tpu" else "jnp"
+
+
 def decode_attention(q, cache_k, cache_v, valid, *, pos=None, window=None,
                      chunk=None, rolling=False, impl="auto", interpret=None):
     if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
+        impl = default_impl()
     if impl == "pallas":
         if interpret is None:
             interpret = jax.default_backend() != "tpu"

@@ -34,7 +34,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     ki = pl.program_id(2)
     num_kv = pl.num_programs(2)
 
-    q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q) + q_offset
+    # tile bounds as scalars: Mosaic cannot index a vector by position
+    q_lo = qi * block_q + q_offset
+    q_hi = q_lo + block_q - 1
     k_lo = ki * block_k
     k_hi = k_lo + block_k - 1
 
@@ -47,12 +49,12 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     # tile-level early-out: skip tiles fully outside the mask
     live = (k_lo < seq_kv)
     if causal:
-        live &= k_lo <= q_pos[-1]
+        live &= k_lo <= q_hi
     if window is not None:
-        live &= k_hi > q_pos[0] - window
+        live &= k_hi > q_lo - window
     if chunk is not None:
-        live &= (((k_lo // chunk) <= (q_pos[-1] // chunk))
-                 & ((k_hi // chunk) >= (q_pos[0] // chunk)))
+        live &= (((k_lo // chunk) <= (q_hi // chunk))
+                 & ((k_hi // chunk) >= (q_lo // chunk)))
 
     @pl.when(live)
     def _compute():
@@ -60,6 +62,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         k = k_ref[...].astype(jnp.float32)               # [bk, d]
         v = v_ref[...].astype(jnp.float32)
         s = q @ k.T                                      # [bq, bk] on the MXU
+        q_pos = q_lo + jax.lax.iota(jnp.int32, block_q)
         k_pos = k_lo + jax.lax.iota(jnp.int32, block_k)
         mask = (k_pos[None, :] < seq_kv) & (q_pos[:, None] < seq_q + q_offset)
         if causal:

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 
 from . import ref
+from ..grad import reference_vjp
 from .kernel import flash_attention_pallas
 
 NEG_INF = -1e30
@@ -88,17 +89,25 @@ def flash_attention_jnp(q, k, v, *, causal=True, window=None, chunk=None,
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
+def default_impl() -> str:
+    """What ``impl="auto"`` runs: pallas on TPU, jnp otherwise
+    (CPU tests, the dry-run)."""
+    return "pallas" if jax.default_backend() == "tpu" else "jnp"
+
+
 def flash_attention(q, k, v, *, causal=True, window=None, chunk=None,
                     q_offset=0, impl="auto", interpret=None):
-    """Dispatch: pallas on TPU, jnp_chunked otherwise (incl. dry-run)."""
+    """Dispatch on ``impl`` (``default_impl()`` for "auto")."""
     if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
+        impl = default_impl()
     if impl == "pallas":
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
-        return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      chunk=chunk, q_offset=q_offset,
-                                      interpret=interpret)
+        kw = dict(causal=causal, window=window, chunk=chunk,
+                  q_offset=q_offset)
+        return reference_vjp(
+            lambda *a: flash_attention_pallas(*a, **kw, interpret=interpret),
+            lambda *a: flash_attention_jnp(*a, **kw), q, k, v)
     if impl == "jnp":
         return flash_attention_jnp(q, k, v, causal=causal, window=window,
                                    chunk=chunk, q_offset=q_offset)

@@ -8,15 +8,15 @@ chunks of ``block_t`` tokens.  Per (batch x head, chunk) program:
   the sequential chunk grid axis;
 - within-chunk cumulative log-decays are produced with a lower-triangular
   ones matmul (MXU) instead of ``cumsum`` (unsupported scan on TPU);
-- the intra-chunk attention uses the *explicit* decay tensor
-  ``exp(Lprev[t] - L[s])`` [C, C, K]: every exponent is <= 0 for s <= t-1,
-  so the computation is overflow-safe for arbitrarily strong decays (the
-  factorised form ``e^{+a} e^{-b}`` is not);
+- the intra-chunk attention uses the *explicit* decay
+  ``exp(Lprev[t] - L[s])``, one [C, C] plane per key channel accumulated
+  into the scores: every exponent is <= 0 for s <= t-1, so the computation
+  is overflow-safe for arbitrarily strong decays (the factorised form
+  ``e^{+a} e^{-b}`` is not);
 - the value contraction ``scores @ V`` and the state update run on the MXU.
 
-VMEM working set: 4 x [C, K] inputs + [C, C, K] decay + [K, V] state
-= (4*128 + 128*128 + 64) * 64 * 4B ~ 4.5 MB at C=128, K=64 — well inside
-v5e's 16 MB in fp32.
+VMEM working set: 4 x [C, K] inputs + two [C, C] planes + [K, V] state
+= (4*128*64 + 2*128*128 + 64*64) * 4B ~ 0.3 MB at C=128, K=64.
 
 Validated on CPU in interpret mode against ``ref.rwkv6_reference``.
 """
@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .._compat import CompilerParams as _CompilerParams
 
 LOG_W_MIN = -60.0  # clamp: decays below e^-60 are numerically zero anyway
 
@@ -60,12 +58,16 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
     lw_prev = lw - logw                           # exclusive (L[t-1]; 0 at t=0)
 
     # ---- intra-chunk: scores[t, s] = sum_i r[t,i] k[s,i] e^{Lprev[t,i]-L[s,i]}
-    # explicit decay tensor; exponents <= 0 for the surviving (s <= t-1) terms
-    decay3 = jnp.exp(
-        jnp.minimum(lw_prev[:, None, :] - lw[None, :, :], 0.0))  # [C, C, K]
-    strict = (col >= row)[..., None]              # keep only s <= t-1
-    prod = (r[:, None, :] * k[None, :, :]) * decay3
-    scores = jnp.where(strict, 0.0, prod).sum(axis=-1)           # [C, C]
+    # accumulated one key channel at a time with 2-D [C, C] ops (Mosaic has
+    # no [C, C, K] shape cast); exponents <= 0 for the surviving s <= t-1
+    k_t = k.T                                     # [K, C]
+    lw_t = lw.T
+    scores = jnp.zeros((c, c), jnp.float32)
+    for i in range(k.shape[1]):
+        decay = jnp.exp(jnp.minimum(lw_prev[:, i:i + 1] - lw_t[i:i + 1, :],
+                                    0.0))         # [C, C]
+        scores = scores + (r[:, i:i + 1] * k_t[i:i + 1, :]) * decay
+    scores = jnp.where(col < row, scores, 0.0)    # keep only s <= t-1
     # diagonal bonus term u
     bonus = (r * u * k).sum(axis=-1)              # [C]
     scores = scores + jnp.where(col == row, bonus[:, None], 0.0)
@@ -107,13 +109,13 @@ def rwkv6_pallas(r, k, v, w, u, *, block_t: int = 128, interpret: bool = False):
             pl.BlockSpec((None, block_t, kk), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((None, block_t, kk), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((None, block_t, kk), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, kk), lambda bh, ci, h=h: (bh % h, 0)),
+            pl.BlockSpec((None, 1, kk), lambda bh, ci, h=h: (bh % h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, block_t, kk), lambda bh, ci: (bh, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, t, kk), r.dtype),
         scratch_shapes=[pltpu.VMEM((kk, kk), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(rf, kf, vf, wf, u)
+    )(rf, kf, vf, wf, u.reshape(h, 1, kk))
     return out.reshape(b, h, t, kk).transpose(0, 2, 1, 3)

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
+from ..grad import reference_vjp
 from .kernel import rwkv6_pallas, LOG_W_MIN
 
 
@@ -84,8 +85,9 @@ def rwkv6(r, k, v, w, u, *, impl: str = "auto", block_t: int = 128,
     if impl == "pallas":
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
-        return rwkv6_pallas(r, k, v, w, u, block_t=block_t,
-                            interpret=interpret)
+        return reference_vjp(
+            lambda *a: rwkv6_pallas(*a, block_t=block_t, interpret=interpret),
+            lambda *a: rwkv6_jnp(*a, block_t=block_t), r, k, v, w, u)
     if impl == "jnp":
         return rwkv6_jnp(r, k, v, w, u, block_t=block_t)
     if impl == "ref":

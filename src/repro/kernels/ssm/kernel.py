@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams as _CompilerParams
-
 LOG_A_MIN = -60.0
 
 
@@ -98,16 +96,18 @@ def ssd_pallas(x, dt, a_log, b, c, d, *, block_t: int = 128,
         in_specs=[
             pl.BlockSpec((None, block_t, p), lambda g, ci: (g, ci, 0)),
             pl.BlockSpec((None, block_t, 1), lambda g, ci: (g, ci, 0)),
-            pl.BlockSpec((1, 1), lambda g, ci, h=h: (g % h, 0)),
+            # per-head scalars as [H, 1, 1]: a (1, 1) block of an [H, 1]
+            # array is not (8, 128)-tileable, a squeezed-head one is
+            pl.BlockSpec((None, 1, 1), lambda g, ci, h=h: (g % h, 0, 0)),
             pl.BlockSpec((None, block_t, n), lambda g, ci, h=h: (g // h, ci, 0)),
             pl.BlockSpec((None, block_t, n), lambda g, ci, h=h: (g // h, ci, 0)),
-            pl.BlockSpec((1, 1), lambda g, ci, h=h: (g % h, 0)),
+            pl.BlockSpec((None, 1, 1), lambda g, ci, h=h: (g % h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, block_t, p), lambda g, ci: (g, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((bs * h, t, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(xf, dtf, a_log.reshape(h, 1), b, c, d.reshape(h, 1))
+    )(xf, dtf, a_log.reshape(h, 1, 1), b, c, d.reshape(h, 1, 1))
     return y.reshape(bs, h, t, p).transpose(0, 2, 1, 3)

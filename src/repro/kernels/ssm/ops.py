@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
+from ..grad import reference_vjp
 from .kernel import ssd_pallas, LOG_A_MIN
 
 
@@ -82,8 +83,9 @@ def ssd(x, dt, a_log, b, c, d, *, impl: str = "auto", block_t: int = 128,
     if impl == "pallas":
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
-        return ssd_pallas(x, dt, a_log, b, c, d, block_t=block_t,
-                          interpret=interpret)
+        return reference_vjp(
+            lambda *a: ssd_pallas(*a, block_t=block_t, interpret=interpret),
+            lambda *a: ssd_jnp(*a, block_t=block_t), x, dt, a_log, b, c, d)
     if impl == "jnp":
         return ssd_jnp(x, dt, a_log, b, c, d, block_t=block_t)
     if impl == "ref":

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.api import build_model
 from repro.models.params import init_params
@@ -65,7 +66,9 @@ def serve(argv=None):
     batch.pop("labels", None)
     t0 = time.perf_counter()
     last_logits = prefill(params, batch)
-    next_tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+    # on the host: the decode step places the first tokens as it places
+    # its own, whatever layout the prefill's logits came back in
+    next_tok = np.asarray(jnp.argmax(last_logits, axis=-1).astype(jnp.int32))
     t_prefill = time.perf_counter() - t0
     print(f"prefill: {b} x {args.prompt_len} tokens in {t_prefill:.3f}s")
 
@@ -93,4 +96,5 @@ def serve(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     serve()

@@ -12,12 +12,14 @@ from __future__ import annotations
 import argparse
 import logging
 import time
+from dataclasses import dataclass
 
 import jax
 
 from repro.checkpoint import CheckpointManager, latest_step, restore_pytree
 from repro.configs import ARCH_IDS, get_config
 from repro.data import SyntheticTokenDataset, make_global_batch
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.api import build_model
 from repro.runtime import (FailureInjector, ShardingRules, StragglerMonitor,
@@ -27,7 +29,7 @@ from repro.runtime.steps import build_train_step, make_train_state
 log = logging.getLogger("repro.train")
 
 
-def train(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen2-0.5b")
     ap.add_argument("--steps", type=int, default=50)
@@ -47,28 +49,54 @@ def train(argv=None):
     ap.add_argument("--failure-rate", type=float, default=0.0)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+@dataclass
+class TrainSetup:
+    """What ``train`` runs for its arguments on one mesh (or one device)."""
+
+    mesh: object
+    step_fn: object
+    shardings: object
+    state: object
+    ds: SyntheticTokenDataset
+
+    def batch(self, step: int) -> dict:
+        if self.mesh is not None:
+            return make_global_batch(self.ds, step, self.mesh)
+        return {k: jax.numpy.asarray(v)
+                for k, v in self.ds.host_batch(step).items()}
+
+
+def setup(args: argparse.Namespace, mesh) -> TrainSetup:
+    """Model, optimizer schedule, step, initial state and data for ``args``;
+    ``mesh=None`` runs on the default device."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     # minicpm trains under WSD per its paper
     schedule = "wsd" if args.arch == "minicpm-2b" else args.schedule
     model = build_model(cfg)
+    opts = TrainOptions(peak_lr=args.lr, warmup=max(2, args.steps // 20),
+                        total_steps=args.steps, schedule=schedule,
+                        microbatches=args.microbatches)
+    step_fn, shardings = build_train_step(model, mesh, ShardingRules(), opts)
+    state = make_train_state(model, jax.random.PRNGKey(0))
+    ds = SyntheticTokenDataset(cfg.vocab_size, args.seq, args.batch)
+    return TrainSetup(mesh, step_fn, shardings, state, ds)
 
+
+def train(argv=None):
+    args = parse_args(argv)
     if args.production_mesh:
         mesh = make_production_mesh(multi_pod=args.multi_pod)
     elif len(jax.devices()) > 1:
         mesh = make_host_mesh()
     else:
         mesh = None
-    rules = ShardingRules()
-    opts = TrainOptions(peak_lr=args.lr, warmup=max(2, args.steps // 20),
-                        total_steps=args.steps, schedule=schedule,
-                        microbatches=args.microbatches)
-    step_fn, shardings = build_train_step(model, mesh, rules, opts)
-
-    state = make_train_state(model, jax.random.PRNGKey(0))
+    run = setup(args, mesh)
+    step_fn, shardings, state = run.step_fn, run.shardings, run.state
     start = 0
     mgr = CheckpointManager(args.ckpt_dir, interval=args.ckpt_interval)
     if args.resume and (ls := latest_step(args.ckpt_dir)) is not None:
@@ -77,7 +105,6 @@ def train(argv=None):
         start = ls + 1
         log.info("resumed from step %d", ls)
 
-    ds = SyntheticTokenDataset(cfg.vocab_size, args.seq, args.batch)
     injector = FailureInjector(rate=args.failure_rate, seed=1)
     monitor = StragglerMonitor()
 
@@ -95,11 +122,7 @@ def train(argv=None):
                 s = ls + 1
             log.warning("injected failure; restarted at step %d", s)
             continue
-        if mesh is not None:
-            batch = make_global_batch(ds, s, mesh)
-        else:
-            hb = ds.host_batch(s)
-            batch = {k: jax.numpy.asarray(v) for k, v in hb.items()}
+        batch = run.batch(s)
         t0 = time.perf_counter()
         state, metrics = step_fn(state, batch)
         loss = float(metrics["loss"])
@@ -119,4 +142,5 @@ def train(argv=None):
 
 if __name__ == "__main__":
     logging.basicConfig(level=logging.INFO)
+    use_compile_cache()
     train()

@@ -13,7 +13,6 @@ from repro.kernels.flash_attention import ops as fa
 from repro.kernels.decode_attention import ops as da
 from repro.runtime.sharding import (current_flags, current_mesh,
                                     current_rules, gathered, shard_act)
-from ._compat import shard_map
 from .config import ModelConfig
 from .layers import apply_rope, rms_norm
 from .params import spec
@@ -78,21 +77,52 @@ def layer_mask_kind(cfg: ModelConfig, layer_idx) -> dict:
     return dict(window=cfg.sliding_window, chunk=cfg.chunk_size, rope=True)
 
 
-def _headparallel_flash(q, k, v, mesh, batch_axes, **kw):
-    """§Perf variant: explicit head-parallel attention.  Each model rank
-    runs the flash scan on its own heads with NO collectives inside — the
-    alternative (GSPMD inferring layouts for the blocked scan) reconciles
-    fwd/remat/bwd layouts with score-sized all-gathers/all-reduces
-    (measured 580 GB/device/step on llama4 train)."""
-    bspec = (batch_axes if len(batch_axes) > 1
-             else (batch_axes[0] if batch_axes else None))
+def _batch_axes(mesh, logical: str, b: int) -> tuple[str, ...]:
+    """Mesh axes of the ``logical`` batch axis whose product divides ``b``
+    (the model axis never carries the batch here)."""
+    axes: list[str] = []
+    size = 1
+    for a in current_rules().mesh_axes_for(logical, mesh):
+        if a != "model" and b % (size * mesh.shape[a]) == 0:
+            axes.append(a)
+            size *= mesh.shape[a]
+    return tuple(axes)
 
+
+def _entry(axes: tuple[str, ...]):
+    return axes if len(axes) > 1 else (axes[0] if axes else None)
+
+
+def _shardmapped_flash(q, k, v, mesh, batch_axes, heads_axis, **kw):
+    """Flash attention per device under shard_map, batch over
+    ``batch_axes`` and heads over ``heads_axis`` (None: every rank of the
+    model axis computes all heads).  Two callers:
+
+    - the Pallas kernel on a mesh: GSPMD cannot partition a Mosaic call;
+    - §Perf head parallelism: each model rank runs the flash scan on its
+      own heads with NO collectives inside — the alternative (GSPMD
+      inferring layouts for the blocked scan) reconciles fwd/remat/bwd
+      layouts with score-sized all-gathers/all-reduces (measured
+      580 GB/device/step on llama4 train)."""
     def body(q, k, v):
         return fa.flash_attention(q, k, v, **kw)
 
-    spec = P(bspec, None, "model", None)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    spec = P(_entry(batch_axes), None, heads_axis, None)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
+
+
+def _shardmapped_decode(q, cache_k, cache_v, valid, pos, mesh, batch_axes,
+                        **kw):
+    """Decode attention per device under shard_map, batch over
+    ``batch_axes``: GSPMD cannot partition the Pallas decode kernel."""
+    def body(q, ck, cv, valid, pos):
+        return da.decode_attention(q, ck, cv, valid, pos=pos, **kw)
+
+    spec = P(_entry(batch_axes))
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 5,
+                         out_specs=spec, check_vma=False)(
+        q, cache_k, cache_v, valid, pos)
 
 
 def self_attention(p, x, cfg: ModelConfig, positions, *, causal=True,
@@ -102,14 +132,14 @@ def self_attention(p, x, cfg: ModelConfig, positions, *, causal=True,
     b, s = x.shape[:2]
     mesh = current_mesh()
     m = mesh.shape.get("model", 1) if mesh is not None else 1
-    if (current_flags().get("headparallel_attn") and mesh is not None
-            and m > 1 and cfg.num_heads % m == 0
-            and cfg.num_kv_heads % m == 0):
-        rules = current_rules()
-        baxes = tuple(a for a in rules.mesh_axes_for("batch", mesh)
-                      if a != "model" and b % mesh.shape[a] == 0)
-        out = _headparallel_flash(q, k, v, mesh, baxes, causal=causal,
-                                  window=window, chunk=chunk)
+    split_heads = (m > 1 and cfg.num_heads % m == 0
+                   and cfg.num_kv_heads % m == 0)
+    if mesh is not None and (
+            fa.default_impl() == "pallas"
+            or (current_flags().get("headparallel_attn") and split_heads)):
+        out = _shardmapped_flash(q, k, v, mesh, _batch_axes(mesh, "batch", b),
+                                 "model" if split_heads else None,
+                                 causal=causal, window=window, chunk=chunk)
     else:
         q = shard_act(q, "batch", "seq", "act_heads", None)
         out = fa.flash_attention(q, k, v, causal=causal, window=window,
@@ -128,8 +158,7 @@ def _sharded_flash_decode(q, k, v, cache_k, cache_v, pos, mesh, batch_axes):
     s_max = cache_k.shape[1]
     m = mesh.shape["model"]
     s_loc = s_max // m
-    bspec = (batch_axes if len(batch_axes) > 1
-             else (batch_axes[0] if batch_axes else None))
+    bspec = _entry(batch_axes)
 
     def body(q, k, v, ck, cv, pos):
         rank = jax.lax.axis_index("model")
@@ -145,7 +174,7 @@ def _sharded_flash_decode(q, k, v, cache_k, cache_v, pos, mesh, batch_axes):
         out = da.combine_partials(acc, mx, l, "model")
         return out[:, None].astype(q.dtype), ck, cv
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec), P(bspec), P(bspec),
                   P(bspec, "model"), P(bspec, "model"), P(bspec)),
@@ -169,11 +198,9 @@ def decode_attention(p, x, cfg: ModelConfig, cache_k, cache_v, pos, *,
     if (current_flags().get("sharded_decode") and mesh is not None
             and "model" in mesh.axis_names and window is None
             and chunk is None and s_max % mesh.shape["model"] == 0):
-        rules = current_rules()
-        baxes = tuple(a for a in rules.mesh_axes_for("cache_batch", mesh)
-                      if b % mesh.shape[a] == 0)
         out, cache_k, cache_v = _sharded_flash_decode(
-            q, k, v, cache_k, cache_v, pos, mesh, baxes)
+            q, k, v, cache_k, cache_v, pos, mesh,
+            _batch_axes(mesh, "cache_batch", b))
         out = out.reshape(b, 1, cfg.q_dim)
         return out @ p["wo"].astype(x.dtype), cache_k, cache_v
     if window is not None and s_max <= window:
@@ -189,10 +216,15 @@ def decode_attention(p, x, cfg: ModelConfig, cache_k, cache_v, pos, *,
         lambda c, vv, i: jax.lax.dynamic_update_slice(c, vv, (i, 0, 0))
     )(cache_v, v.astype(cache_v.dtype), slot)
     valid = jnp.minimum(pos + 1, s_max)
-    out = da.decode_attention(
-        q[:, 0], cache_k, cache_v, valid,
-        pos=pos, window=window, chunk=chunk, rolling=window is not None
-        and s_max <= window)
+    kw = dict(window=window, chunk=chunk,
+              rolling=window is not None and s_max <= window)
+    if mesh is not None and da.default_impl() == "pallas":
+        out = _shardmapped_decode(q[:, 0], cache_k, cache_v, valid, pos,
+                                  mesh, _batch_axes(mesh, "cache_batch", b),
+                                  **kw)
+    else:
+        out = da.decode_attention(q[:, 0], cache_k, cache_v, valid, pos=pos,
+                                  **kw)
     out = out.reshape(b, 1, cfg.q_dim)
     return out @ p["wo"].astype(x.dtype), cache_k, cache_v
 

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.runtime.sharding import current_flags, current_mesh, current_rules
-from ._compat import shard_map
 from .config import ModelConfig
 from .params import spec
 
@@ -199,7 +198,7 @@ def moe_ep_a2a(p, x, cfg: ModelConfig, opts: MoEOptions = MoEOptions()):
     seq_entry = "model" if "model" not in baxes else None
     xspec = P(baxes if len(baxes) > 1 else (baxes[0] if baxes else None),
               seq_entry, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), P("model"), P("model"), P("model"), xspec),
         out_specs=(xspec, P()),
@@ -239,7 +238,7 @@ def moe_ep_psum(p, x, cfg: ModelConfig, opts: MoEOptions = MoEOptions()):
 
     rules = current_rules()
     xspec = P(rules.mesh_axes_for("batch", mesh) or None, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), P("model"), P("model"), P("model"), xspec),
         out_specs=(xspec, P()),

@@ -135,7 +135,9 @@ def build_train_step(model: Model, mesh=None,
         return new, {"loss": loss, "grad_norm": gnorm, "lr": lr}
 
     if mesh is None:
-        return jax.jit(train_step), None
+        # the state is donated, as on the mesh: the step updates it in
+        # place instead of holding a second copy of params and moments
+        return jax.jit(train_step, donate_argnums=(0,)), None
 
     shardings = state_shardings(model, mesh, rules)
     rep = NamedSharding(mesh, P())
@@ -190,7 +192,7 @@ def build_decode_step(model: Model, mesh=None,
             return next_tok, logits.astype(jnp.float32), cache
 
     if mesh is None:
-        return jax.jit(decode), None
+        return jax.jit(decode, donate_argnums=(1,)), None
     ps = param_shardings(model.specs(), mesh, rules)
     cs = cache_shardings(model, batch, s_max, mesh, rules)
     spec_b = _batch_entry(_batch_axes(rules, mesh, batch))

@@ -75,10 +75,9 @@ def test_sharded_train_step_matches_single_device():
 @pytest.mark.slow
 def test_moe_ep_matches_local():
     """EP all_to_all / psum vs the local reference.  Root cause of the
-    historical xfail: the `jax.shard_map` top-level API does not exist on
-    jax 0.4.x, so the subprocess died with AttributeError before computing
-    anything — not numerics.  With the `models/_compat.shard_map` shim the
-    drift is well inside 2e-3 (float32 dispatch order only)."""
+    historical xfail: the subprocess died before computing anything, on a
+    jax without the top-level `jax.shard_map` — not numerics.  The drift is
+    well inside 2e-3 (float32 dispatch order only)."""
     res = run_sub("""
         import dataclasses
         from repro.configs import get_config
@@ -117,14 +116,11 @@ def test_moe_ep_matches_local():
 @pytest.mark.slow
 def test_flash_decoding_shard_map_combine():
     """Explicit sequence-sharded decode: shard_map partial softmax + psum
-    log-sum-exp combine equals the dense reference.  Root cause of the
-    historical xfail: `jax.shard_map` is absent on jax 0.4.x (AttributeError
-    in the subprocess), not combine-dtype drift; the float32 log-sum-exp
-    combine is stable to <1e-4 once run through the compat shim."""
+    log-sum-exp combine equals the dense reference.  The float32 log-sum-exp
+    combine is stable to <1e-4."""
     res = run_sub("""
         from repro.kernels.decode_attention import ops as da
         from repro.kernels.decode_attention import ref as dref
-        from repro.models._compat import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         import numpy as _np
 
@@ -144,7 +140,7 @@ def test_flash_decoding_shard_map_combine():
             acc, m, l = da.partial_decode(q, ck, cv, mask)
             return da.combine_partials(acc, m, l, "model")
 
-        out = jax.jit(shard_map(
+        out = jax.jit(jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(), P(None, "model"), P(None, "model"), P(),
                       P("model")),
@@ -153,3 +149,58 @@ def test_flash_decoding_shard_map_combine():
             {"diff": float(jnp.abs(out.reshape(b, h, d) - want).max())}))
     """)
     assert res["diff"] < 1e-4, res
+
+
+@pytest.mark.slow
+def test_pallas_attention_on_a_mesh():
+    """On a mesh the Pallas attention kernels run per device under
+    shard_map (GSPMD cannot partition a Mosaic call).  The TPU dispatch is
+    forced here, with the kernels interpreted, and the sharded train loss
+    and decode logits must match the single-device jnp path."""
+    res = run_sub("""
+        from repro.configs import get_config
+        from repro.kernels.decode_attention import ops as da
+        from repro.kernels.flash_attention import ops as fa
+        from repro.models.api import build_model
+        from repro.models.params import init_params
+        from repro.runtime import ShardingRules, TrainOptions
+        from repro.runtime.steps import (build_decode_step,
+                                         build_train_step, make_train_state)
+        from jax.sharding import Mesh
+        import numpy as _np
+
+        cfg = get_config("qwen2-0.5b").reduced()
+        model = build_model(cfg)
+        batch = model.make_batch(jax.random.PRNGKey(1), batch=8, seq=32)
+        opts = TrainOptions(total_steps=10, remat=False)
+        params = init_params(model.specs(), jax.random.PRNGKey(0))
+        ks = jax.random.split(jax.random.PRNGKey(2), 2)
+        cache = {n: jax.random.normal(k, s.shape).astype(s.dtype)
+                 for (n, s), k in zip(model.cache_specs(8, 64).items(), ks)}
+        tok = jnp.arange(8, dtype=jnp.int32)[:, None]
+        pos = jnp.arange(8, dtype=jnp.int32) * 5
+
+        def run(mesh, rules):
+            step, _ = build_train_step(model, mesh, rules, opts)
+            state = make_train_state(model, jax.random.PRNGKey(0))
+            jaxpr = str(jax.make_jaxpr(step)(state, batch))
+            _, m = step(state, batch)
+            dec, _ = build_decode_step(model, mesh, rules, batch=8, s_max=64)
+            _, logits, _ = dec(params, jax.tree.map(jnp.copy, cache), tok,
+                               pos)
+            return float(m["loss"]), _np.asarray(logits), jaxpr
+
+        loss1, logits1, _ = run(None, None)
+        fa.default_impl = da.default_impl = lambda: "pallas"
+        mesh = Mesh(_np.asarray(jax.devices()).reshape(4, 2),
+                    ("data", "model"))
+        loss2, logits2, jaxpr = run(mesh, ShardingRules())
+        print(json.dumps({
+            "loss1": loss1, "loss2": loss2,
+            "logits_diff": float(_np.abs(logits1 - logits2).max()),
+            "kernel_under_shard_map": "shard_map" in jaxpr
+                                      and "pallas_call" in jaxpr}))
+    """)
+    assert res["kernel_under_shard_map"], res
+    assert abs(res["loss1"] - res["loss2"]) < 5e-2, res
+    assert res["logits_diff"] < 5e-2, res

@@ -127,9 +127,11 @@ def test_rwkv6_strong_decay_stability():
     w = jnp.full((b, t, h, k), 1e-30, jnp.float32)
     u = _rand(ks[3], (h, k), jnp.float32)
     want, _ = rk_ref.rwkv6_reference(r, kk, v, w, u)
-    got = rk.rwkv6(r, kk, v, w, u, impl="jnp", block_t=16)
-    assert bool(jnp.isfinite(got).all())
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL32)
+    for kw in (dict(impl="jnp"), dict(impl="pallas", interpret=True)):
+        got = rk.rwkv6(r, kk, v, w, u, block_t=16, **kw)
+        assert bool(jnp.isfinite(got).all()), kw
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   **TOL32)
 
 
 def test_rwkv6_decode_matches_scan():
@@ -196,3 +198,56 @@ def test_ssd_decode_matches_scan():
         outs.append(y)
     got = jnp.stack(outs, axis=1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL32)
+
+
+# ---------------------------------------------------------------------------
+# gradients: a train step differentiates through the Pallas path
+# ---------------------------------------------------------------------------
+
+def _grad_case(kernel):
+    """(fn(impl, *args), args) for a small float32 case of ``kernel``."""
+    ks = jax.random.split(jax.random.PRNGKey(8), 6)
+    if kernel == "flash":
+        args = (_rand(ks[0], (1, 64, 4, 16), jnp.float32),
+                _rand(ks[1], (1, 64, 2, 16), jnp.float32),
+                _rand(ks[2], (1, 64, 2, 16), jnp.float32))
+        return (lambda impl, *a: fa.flash_attention(
+            *a, causal=True, window=24, impl=impl,
+            interpret=True if impl == "pallas" else None)), args
+    if kernel == "rwkv6":
+        b, t, h, k = 1, 32, 2, 16
+        args = (_rand(ks[0], (b, t, h, k), jnp.float32),
+                _rand(ks[1], (b, t, h, k), jnp.float32),
+                _rand(ks[2], (b, t, h, k), jnp.float32),
+                jax.nn.sigmoid(_rand(ks[3], (b, t, h, k), jnp.float32)),
+                _rand(ks[4], (h, k), jnp.float32))
+        return (lambda impl, *a: rk.rwkv6(
+            *a, impl=impl, block_t=16,
+            interpret=True if impl == "pallas" else None)), args
+    b, t, h, p, n = 1, 32, 2, 8, 16
+    args = (_rand(ks[0], (b, t, h, p), jnp.float32),
+            jnp.abs(_rand(ks[1], (b, t, h), jnp.float32)) * 0.5,
+            _rand(ks[2], (h,), jnp.float32) * 0.5,
+            _rand(ks[3], (b, t, n), jnp.float32),
+            _rand(ks[4], (b, t, n), jnp.float32),
+            _rand(ks[5], (h,), jnp.float32))
+    return (lambda impl, *a: sk.ssd(
+        *a, impl=impl, block_t=16,
+        interpret=True if impl == "pallas" else None)), args
+
+
+@pytest.mark.parametrize("kernel", ["flash", "rwkv6", "ssd"])
+def test_pallas_grad_matches_reference(kernel):
+    """``pallas_call`` has no transpose rule: the kernels' VJP comes from
+    their jnp form, and must equal the gradient of the plain reference."""
+    fn, args = _grad_case(kernel)
+    argnums = tuple(range(len(args)))
+
+    def loss(impl):
+        return lambda *a: (fn(impl, *a) ** 2).sum()
+
+    got = jax.grad(loss("pallas"), argnums)(*args)
+    want = jax.grad(loss("ref"), argnums)(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-3, atol=2e-3)
