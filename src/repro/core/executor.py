@@ -53,11 +53,12 @@ from typing import Sequence
 from .dag import DAG
 from .estimator import FeedbackOptions  # noqa: F401 (re-export surface)
 from .resources import Allocation, PoolSpec
-from .results import RunResult, TaskRecord
+from .results import PerfCounters, RunResult, TaskRecord
 from .runconfig import _LEGACY, RunConfig, resolve_run_config
 from .sched_engine import AdmissionOptions, SchedEngine, SchedulingPolicy
 from .simulator import Mode
 from .stream import WorkflowStream, prefix_view
+from .tracing import span
 from .workflow import Campaign, CampaignView, campaign_stats
 from ..runtime.fault import FailureSchedule, FaultOptions
 
@@ -128,6 +129,7 @@ class RealExecutor:
         feedback = cfg.feedback
         admission = cfg.admission
         faults = cfg.faults
+        perf = PerfCounters() if cfg.perf_counters else None
         if cfg.record_policy != "full":
             # the executor's records ARE its measurement (wall-clock
             # spans); only the simulator can trade them for sketches
@@ -374,12 +376,21 @@ class RealExecutor:
                                           workflow=wf_of.get(name, "")))
                 cv.notify_all()
 
-        def attempt(name: str, i: int, *args) -> None:
-            """Worker entry: ``body``, with its exception also handed to
-            the dispatcher, which would otherwise wait for a completion
-            that never comes."""
+        def attempt(submitted: float, name: str, i: int, *args) -> None:
+            """Worker entry: ``body`` under an ``exec:task`` span, with its
+            exception also handed to the dispatcher, which would otherwise
+            wait for a completion that never comes.  ``submitted`` is the
+            clock at ``ex.submit``: the hand-off runs from there to here."""
+            handoff = time.perf_counter() - submitted
+            if perf is not None:
+                with cv:
+                    perf.starts += 1
+                    perf.handoff_s += handoff
+                    perf.handoff_max_s = max(perf.handoff_max_s, handoff)
             try:
-                body(name, i, *args)
+                with span("exec:task", task=f"{name}[{i}]",
+                          handoff_us=handoff * 1e6):
+                    body(name, i, *args)
             except BaseException as err:
                 with cv:
                     failure.append((name, i, err))
@@ -406,8 +417,12 @@ class RealExecutor:
         def stream_pending() -> bool:
             return stream is not None and stream.next_arrival() is not None
 
+        def submit(*args) -> None:
+            ex.submit(attempt, time.perf_counter(), *args)
+
         #: engine snapshot behind the newest prediction (idle-wakeup guard)
         last_stamp = None
+        t_loop = time.perf_counter()
         with ThreadPoolExecutor(max_workers=self.max_workers) as ex:
             with cv:
                 while (not engine.done() or stream_pending()) \
@@ -417,48 +432,54 @@ class RealExecutor:
                     # campaign arrivals gate on the same time base as the
                     # simulator's — and so do failure/recovery, stream
                     # arrival, and elastic lease events
-                    now = (time.perf_counter() - t0) / self.tx_scale
-                    if stream is not None:
-                        new_names: list[str] = []
-                        for w in stream.take_until(now):
-                            arrived_entries.append(w)
-                            new_names.extend(
-                                engine.add_workflow(w, now=now))
-                        sample_durations(new_names)
-                    if now >= next_elastic:
-                        engine.elastic_pass(now)
-                        next_elastic = (now
-                                        + engine.elastic.check_interval)
-                    while recoveries and recoveries[0][0] <= now:
-                        _, rk, rn = heapq.heappop(recoveries)
-                        engine.recover_node(rk, rn, now=now)
-                    while (next_fail is not None and next_fail[0] <= now
-                           and not engine.done()):
-                        _, fk, fn = next_fail
-                        modelled = {k: v / self.tx_scale
-                                    for k, v in started.items()}
-                        ev = engine.fail_node(fk, fn, now=now,
-                                              started=modelled)
-                        if ev is not None:
-                            apply_failure_event(ev)
-                            if math.isfinite(faults.node_recovery_time):
-                                heapq.heappush(
-                                    recoveries,
-                                    (now + faults.node_recovery_time,
-                                     fk, fn))
-                        next_fail = schedule.next_node_failure()
-                    batch = engine.startable(now)
-                    for name, i, pool_idx in batch:
-                        if faults is None:
-                            ex.submit(attempt, name, i, pool_idx, 0)
-                            continue
-                        d = engine.dispatch_duration(
-                            name, i, durations[(name, i)], pool_idx)
-                        frac = schedule.attempt_failure(
-                            name, i, engine.attempt_number(name, i))
-                        ex.submit(attempt, name, i, pool_idx,
-                                  gen.get((name, i), 0), 0.0, d, False,
-                                  frac)
+                    t_pass = time.perf_counter() if perf is not None else 0.0
+                    with span("exec:pass") as pass_span:
+                        now = (time.perf_counter() - t0) / self.tx_scale
+                        if stream is not None:
+                            new_names: list[str] = []
+                            for w in stream.take_until(now):
+                                arrived_entries.append(w)
+                                new_names.extend(
+                                    engine.add_workflow(w, now=now))
+                            sample_durations(new_names)
+                        if now >= next_elastic:
+                            engine.elastic_pass(now)
+                            next_elastic = (now
+                                            + engine.elastic.check_interval)
+                        while recoveries and recoveries[0][0] <= now:
+                            _, rk, rn = heapq.heappop(recoveries)
+                            engine.recover_node(rk, rn, now=now)
+                        while (next_fail is not None
+                               and next_fail[0] <= now
+                               and not engine.done()):
+                            _, fk, fn = next_fail
+                            modelled = {k: v / self.tx_scale
+                                        for k, v in started.items()}
+                            ev = engine.fail_node(fk, fn, now=now,
+                                                  started=modelled)
+                            if ev is not None:
+                                apply_failure_event(ev)
+                                if math.isfinite(faults.node_recovery_time):
+                                    heapq.heappush(
+                                        recoveries,
+                                        (now + faults.node_recovery_time,
+                                         fk, fn))
+                            next_fail = schedule.next_node_failure()
+                        batch = engine.startable(now)
+                        for name, i, pool_idx in batch:
+                            if faults is None:
+                                submit(name, i, pool_idx, 0)
+                                continue
+                            d = engine.dispatch_duration(
+                                name, i, durations[(name, i)], pool_idx)
+                            frac = schedule.attempt_failure(
+                                name, i, engine.attempt_number(name, i))
+                            submit(name, i, pool_idx, gen.get((name, i), 0),
+                                   0.0, d, False, frac)
+                        pass_span.set_metadata(started=len(batch))
+                    if perf is not None:
+                        perf.engine_s += time.perf_counter() - t_pass
+                        perf.passes += 1
                     if (not engine.done() or stream_pending()) \
                             and not batch and not failure:
                         # with mitigation on, the wait doubles as the
@@ -482,7 +503,14 @@ class RealExecutor:
                         if nxt is not None:
                             timeout = min(timeout, max(
                                 0.0, (nxt - now) * self.tx_scale) + 1e-3)
-                        cv.wait(timeout=timeout)
+                        t_wait = (time.perf_counter() if perf is not None
+                                  else 0.0)
+                        with span("exec:wait") as wait_span:
+                            woken = cv.wait(timeout=timeout)
+                            wait_span.set_metadata(timeout=int(not woken))
+                        if perf is not None:
+                            perf.wait_s += time.perf_counter() - t_wait
+                            perf.wait_timeouts += not woken
                     # scheduling pass on the modelled clock (see observe)
                     now = (time.perf_counter() - t0) / self.tx_scale
                     modelled = {k: v / self.tx_scale
@@ -500,17 +528,15 @@ class RealExecutor:
                                 # straggler clock pauses until the re-run's
                                 # worker stamps its own start
                                 started.pop((sn, si), None)
-                                ex.submit(attempt, sn, si, dst, gen[(sn, si)],
-                                          cost,
-                                          engine.tx_estimate(sn, pool=dst))
+                                submit(sn, si, dst, gen[(sn, si)], cost,
+                                       engine.tx_estimate(sn, pool=dst))
                                 # wake preempted synthetic sleeps so they
                                 # release their worker slots promptly
                                 cv.notify_all()
                             else:  # speculate: a duplicate races the task
-                                ex.submit(attempt, sn, si, dst,
-                                          spec_gen.get((sn, si), 0), cost,
-                                          engine.tx_estimate(sn, pool=dst),
-                                          True)
+                                submit(sn, si, dst, spec_gen.get((sn, si), 0),
+                                       cost, engine.tx_estimate(sn, pool=dst),
+                                       True)
                     if replicating:
                         # proactively duplicate at-risk tasks onto another
                         # node through the speculation machinery
@@ -519,10 +545,9 @@ class RealExecutor:
                             if rep is None:
                                 continue
                             dst, cost = rep
-                            ex.submit(attempt, rn2, ri2, dst,
-                                      spec_gen.get((rn2, ri2), 0), cost,
-                                      engine.tx_estimate(rn2, pool=dst),
-                                      True)
+                            submit(rn2, ri2, dst, spec_gen.get((rn2, ri2), 0),
+                                   cost, engine.tx_estimate(rn2, pool=dst),
+                                   True)
                     # online makespan re-prediction (core/predictor.py).
                     # The dispatcher's poll loop wakes on a timeout even
                     # when nothing happened; an idle wakeup (no running
@@ -531,8 +556,16 @@ class RealExecutor:
                     # poll — skip those, re-predict on everything else
                     if (modelled or not engine.predictions
                             or engine.predict_stamp() != last_stamp):
-                        engine.repredict(now, modelled)
+                        t_pred = (time.perf_counter() if perf is not None
+                                  else 0.0)
+                        with span("exec:predict"):
+                            engine.repredict(now, modelled)
+                        if perf is not None:
+                            perf.predict_s += time.perf_counter() - t_pred
                         last_stamp = engine.predict_stamp()
+                if perf is not None:
+                    perf.total_s = time.perf_counter() - t_loop
+                    perf.predicts = engine._pred_evals
             if failure:
                 # queued attempts never start; running ones see
                 # ``failure`` at their next check and return
@@ -575,4 +608,5 @@ class RealExecutor:
                           leases_expired=engine.leases_expired,
                           lease_log=engine.lease_log,
                           stream=(engine.stream_accounting()
-                                  if stream is not None else None))
+                                  if stream is not None else None),
+                          perf=perf)

@@ -62,16 +62,27 @@ class TaskRecord:
 @dataclasses.dataclass
 class PerfCounters:
     """Wall-time attribution of one run's hot loop
-    (``RunConfig.perf_counters=True``; all zeros otherwise unused).
+    (``RunConfig.perf_counters=True``), filled by both substrates; a
+    field a substrate does not fill stays zero.
 
-    The buckets partition the substrate's event loop: ``engine_s`` is
+    Simulator: the buckets partition its event loop: ``engine_s`` is
     dispatch passes (``try_start`` + elastic/watchdog scans),
     ``predict_s`` is ``SchedEngine.repredict``, ``metrics_s`` is
     streaming-summary folding, and ``events_s`` is the remaining loop
     wall time (heap pops, event bookkeeping).  ``predicts`` counts
     *evaluated* predictions — throttled/deduped ``repredict`` calls that
     returned a cached prediction are excluded, which is how benchmarks
-    attribute the prediction-epoch win."""
+    attribute the prediction-epoch win.
+
+    ``RealExecutor``: ``engine_s``/``passes`` are its dispatcher's
+    backfill passes (arrivals, faults, ``startable``, submits),
+    ``predict_s``/``predicts`` as above, ``total_s`` the dispatcher
+    loop's wall; and the fields below, which the simulator leaves at
+    zero: ``wait_s`` is the dispatcher's time in its condition wait and
+    ``wait_timeouts`` the waits that ran out their timeout instead of
+    being woken; ``starts`` counts task attempts a worker began, and
+    ``handoff_s``/``handoff_max_s`` sum and bound the time from an
+    attempt's submit to its worker's first line."""
 
     engine_s: float = 0.0
     predict_s: float = 0.0
@@ -81,6 +92,11 @@ class PerfCounters:
     passes: int = 0
     predicts: int = 0
     events: int = 0
+    wait_s: float = 0.0
+    wait_timeouts: int = 0
+    handoff_s: float = 0.0
+    handoff_max_s: float = 0.0
+    starts: int = 0
 
 
 @dataclasses.dataclass

@@ -76,8 +76,10 @@ class RunConfig:
     #: into bounded ``core/metrics.StreamMetrics`` sketches instead,
     #: capping memory on million-task runs
     record_policy: str = "full"
-    #: collect ``RunResult.perf`` hot-loop wall-time attribution
-    #: (pure-Python timers; zero overhead when False)
+    #: collect ``RunResult.perf`` hot-loop wall-time attribution in the
+    #: simulator and in ``RealExecutor`` (which also fills ``wait_s``,
+    #: ``wait_timeouts``, ``handoff_s``, ``handoff_max_s``, ``starts``;
+    #: see ``PerfCounters``) — pure-Python timers; zero overhead when False
     perf_counters: bool = False
     #: engine pass structures: the indexed fast path (default) vs the
     #: brute-force scans (``core/sched_engine.py``); dispatch-identical

@@ -12,6 +12,11 @@ step donates that state, so each payload dispatches under one lock and
 reads the current params there; the device runs dispatched steps in
 order, so a read dispatched before a donation completes before it.
 
+Each payload call is a ``ddmd:<kind>`` span (``repro.core.tracing``); in
+it, ``ddmd:lock`` spans the wait for the lock (not the dispatch under it)
+and ``ddmd:block`` the wait for the device.  ``counters`` sums those two
+waits.
+
 ``run`` drives the workflow through ``RealExecutor`` with the same
 (cpus, gpus) accounting as the paper's middleware: sequential mode
 barriers each stage, async mode staggers the iterations.
@@ -19,8 +24,10 @@ barriers each stage, async mode staggers the iterations.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +35,7 @@ import jax.numpy as jnp
 from repro.core import RealExecutor, RunConfig, deepdrivemd_dag
 from repro.core.executor import ExecResult
 from repro.core.resources import NodeSpec, PoolSpec
+from repro.core.tracing import span
 from repro.core.workflow import ddmd_sequential_stage_groups
 from repro.models.api import Model
 from repro.models.params import init_params
@@ -59,18 +67,33 @@ class PayloadShapes:
     prefill_seq: int = 32
 
 
+@dataclasses.dataclass
+class PayloadCounters:
+    """Host seconds the payloads spent waiting: for the state's lock
+    (per acquire) and in ``block_until_ready`` (per call)."""
+    lock_acquires: int = 0
+    lock_wait_s: float = 0.0
+    lock_wait_max_s: float = 0.0
+    block_s: float = 0.0
+    block_max_s: float = 0.0
+
+
 class DDMDPayloads:
     """The four payloads over one model, its steps compiled ahead of time
     at ``shapes`` (``compiled`` keeps them for memory and HLO checks).
 
     ``losses`` and ``logits_finite`` collect what each call produced, as
-    device scalars, for the caller to check after a run."""
+    device scalars, for the caller to check after a run; ``counters``
+    sums the calls' waits (a caller clears it by replacing it)."""
 
     def __init__(self, model: Model, shapes: PayloadShapes = PayloadShapes()):
         self.model = model
         self.shapes = shapes
         self.state = make_train_state(model, jax.random.PRNGKey(0))
         self._lock = threading.Lock()
+        #: guards the block counters (the lock's own are updated under it)
+        self._count_lock = threading.Lock()
+        self.counters = PayloadCounters()
         self.losses: list[jax.Array] = []
         self.logits_finite: list[jax.Array] = []
         s = shapes
@@ -106,39 +129,73 @@ class DDMDPayloads:
                                                   self.shapes.cache_len),
                            jax.random.PRNGKey(0))
 
+    @contextlib.contextmanager
+    def _locked(self):
+        """Hold the state's lock; the wait for it is a ``ddmd:lock`` span
+        and is counted."""
+        t0 = time.perf_counter()
+        with span("ddmd:lock"):
+            self._lock.acquire()
+        try:
+            wait = time.perf_counter() - t0
+            c = self.counters
+            c.lock_acquires += 1
+            c.lock_wait_s += wait
+            c.lock_wait_max_s = max(c.lock_wait_max_s, wait)
+            yield
+        finally:
+            self._lock.release()
+
+    def _block(self, x):
+        """``block_until_ready`` as a ``ddmd:block`` span, counted."""
+        t0 = time.perf_counter()
+        with span("ddmd:block"):
+            out = jax.block_until_ready(x)
+        wait = time.perf_counter() - t0
+        with self._count_lock:
+            c = self.counters
+            c.block_s += wait
+            c.block_max_s = max(c.block_max_s, wait)
+        return out
+
     def simulation(self, i: int):
         """Decode rollout: ``decode_steps`` tokens per trajectory."""
-        s = self.shapes
-        cache = self._cache()
-        tok = jnp.full((s.decode_batch, 1), 3, jnp.int32)
-        finite = jnp.bool_(True)
-        for t in range(s.decode_steps):
-            pos = jnp.full((s.decode_batch,), t, jnp.int32)
-            with self._lock:
-                nxt, logits, cache = self.compiled["decode"](
-                    self.state.params, cache, tok, pos)
-            finite &= jnp.isfinite(logits).all()
-            tok = nxt[:, None]
-        self.logits_finite.append(finite)
-        return jax.block_until_ready(tok)
+        with span("ddmd:simulation"):
+            s = self.shapes
+            cache = self._cache()
+            tok = jnp.full((s.decode_batch, 1), 3, jnp.int32)
+            finite = jnp.bool_(True)
+            for t in range(s.decode_steps):
+                pos = jnp.full((s.decode_batch,), t, jnp.int32)
+                with self._locked():
+                    nxt, logits, cache = self.compiled["decode"](
+                        self.state.params, cache, tok, pos)
+                finite &= jnp.isfinite(logits).all()
+                tok = nxt[:, None]
+            self.logits_finite.append(finite)
+            return self._block(tok)
 
     def aggregation(self, i: int):
-        x = jax.random.normal(jax.random.PRNGKey(i), (1 << 16,))
-        return jax.block_until_ready(jnp.sort(x)[::64].sum())
+        with span("ddmd:aggregation"):
+            x = jax.random.normal(jax.random.PRNGKey(i), (1 << 16,))
+            return self._block(jnp.sort(x)[::64].sum())
 
     def training(self, i: int):
-        batch = self._train_batch(i)
-        with self._lock:
-            self.state, metrics = self.compiled["train"](self.state, batch)
-        self.losses.append(metrics["loss"])
-        return jax.block_until_ready(metrics["loss"])
+        with span("ddmd:training"):
+            batch = self._train_batch(i)
+            with self._locked():
+                self.state, metrics = self.compiled["train"](self.state,
+                                                             batch)
+            self.losses.append(metrics["loss"])
+            return self._block(metrics["loss"])
 
     def inference(self, i: int):
-        batch = self._prefill_batch(i)
-        with self._lock:
-            logits = self.compiled["prefill"](self.state.params, batch)
-        self.logits_finite.append(jnp.isfinite(logits).all())
-        return jax.block_until_ready(logits)
+        with span("ddmd:inference"):
+            batch = self._prefill_batch(i)
+            with self._locked():
+                logits = self.compiled["prefill"](self.state.params, batch)
+            self.logits_finite.append(jnp.isfinite(logits).all())
+            return self._block(logits)
 
     def as_dict(self) -> dict:
         return dict(simulation=self.simulation, aggregation=self.aggregation,

@@ -6,8 +6,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import (DAG, PoolSpec, NodeSpec, RealExecutor, TaskSet,
-                        cdg_dag, deepdrivemd_dag)
+from repro.core import (DAG, PoolSpec, NodeSpec, RealExecutor, RunConfig,
+                        TaskSet, cdg_dag, deepdrivemd_dag)
 
 SMALL_POOL = PoolSpec("local", num_nodes=1, node=NodeSpec(cpus=8, gpus=4),
                       oversubscribe_cpus=True)
@@ -107,6 +107,81 @@ def test_task_level_executor():
     assert res.tasks_total == sum(ts.num_tasks for ts in g.nodes.values())
 
 
+def test_executor_perf_counters():
+    """``perf_counters`` fills the executor's ``PerfCounters``: every task
+    attempt is counted at its hand-off, and no wait runs out its timeout
+    in a run far shorter than it."""
+    g = DAG()
+    g.add(TaskSet("A", 3, 1, 1, tx_mean=0.03, tx_sigma=0.0))
+    g.add(TaskSet("B", 2, 1, 1, tx_mean=0.03, tx_sigma=0.0))
+    g.add_edge("A", "B")
+    ex = RealExecutor(SMALL_POOL)
+    res = ex.run(g, "async", config=RunConfig(perf_counters=True))
+    p = res.perf
+    assert p is not None
+    assert p.passes > 0 and p.starts == res.tasks_total == 5
+    assert 0.0 <= p.handoff_max_s and p.handoff_max_s <= p.handoff_s
+    assert p.wait_timeouts == 0 and p.wait_s > 0.0
+    assert 0.0 < p.engine_s + p.predict_s + p.wait_s <= p.total_s
+    assert p.predicts <= len(res.predictions)
+    assert ex.run(g, "async").perf is None
+
+
+def test_executor_spans_in_profiler_trace(tmp_path):
+    """The dispatcher's and workers' spans land in a profiler trace with
+    their stats, and each ``exec:task`` encloses its payload's span."""
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    def payload(i):
+        with TraceAnnotation("test:payload"):
+            jnp.ones(8).block_until_ready()
+
+    g = DAG()
+    g.add(TaskSet("A", 2, 1, 1, tx_mean=0.0, payload=payload))
+    g.add(TaskSet("B", 1, 1, 1, tx_mean=0.0, payload=payload))
+    g.add_edge("A", "B")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        RealExecutor(SMALL_POOL).run(g, "async")
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = tmp_path.glob("**/*.xplane.pb")
+    host = ProfileData.from_file(str(pb)).find_plane_with_name("/host:CPU")
+    by_line = [[(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                 dict(e.stats)) for e in line.events] for line in host.lines]
+    events = [ev for line in by_line for ev in line]
+    names = {ev[0] for ev in events}
+    assert {"exec:pass", "exec:wait", "exec:predict", "exec:task"} <= names
+    tasks = [ev for ev in events if ev[0] == "exec:task"]
+    assert sorted(ev[3]["task"] for ev in tasks) == ["A[0]", "A[1]", "B[0]"]
+    assert all(ev[3]["handoff_us"] >= 0 for ev in tasks)
+    assert all(ev[3]["timeout"] == 0 for ev in events
+               if ev[0] == "exec:wait")
+    for line in by_line:
+        for name, s, e, _ in line:
+            if name == "test:payload":
+                assert any(n == "exec:task" and ts <= s and e <= te
+                           for n, ts, te, _ in line)
+    assert sum(ev[0] == "test:payload" for ev in events) == 3
+
+
+def test_span_without_jax(monkeypatch):
+    """Where JAX cannot be imported a span is a no-op that still takes
+    stats, so ``core`` runs without JAX."""
+    import sys
+
+    from repro.core import tracing
+
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    tracing._annotation.cache_clear()
+    try:
+        with tracing.span("exec:wait", timeout=0) as s:
+            s.set_metadata(timeout=1)
+        assert isinstance(s, tracing._NoSpan)
+    finally:
+        tracing._annotation.cache_clear()
+
+
 @pytest.mark.parametrize("mode", ["sequential", "async"])
 def test_raising_payload_ends_run(mode):
     """A payload that raises ends the run: ``run()`` re-raises it with the
@@ -156,12 +231,28 @@ def test_ddmd_real_payloads(ddmd_payloads, mode):
     from repro.launch import ddmd
     p = ddmd_payloads
     n_loss, n_logits = len(p.losses), len(p.logits_finite)
+    p.counters = ddmd.PayloadCounters()
     res, n_tasks = ddmd.run(p, mode)
     assert res.tasks_total == n_tasks == 48
     assert len(p.losses) - n_loss == 3
     assert len(p.logits_finite) - n_logits == 3 * (6 + 6)
     assert all(bool(jnp.isfinite(x)) for x in p.losses)
     assert all(bool(x) for x in p.logits_finite)
+    # one lock acquire per decode step, train step and prefill
+    c = p.counters
+    assert c.lock_acquires == 6 * 3 * p.shapes.decode_steps + 3 + 18
+    assert 0.0 <= c.lock_wait_max_s <= c.lock_wait_s
+    assert 0.0 < c.block_max_s <= c.block_s
+
+
+@pytest.mark.parametrize("step,module", [("train", "jit_train_step"),
+                                         ("prefill", "jit_prefill"),
+                                         ("decode", "jit_decode")])
+def test_step_program_names(ddmd_payloads, step, module):
+    """The device trace names each run of a step by its HLO module, and
+    the benchmark's per-layer readers find the steps by these names."""
+    text = ddmd_payloads.compiled[step].as_text()
+    assert text.split(",", 1)[0] == f"HloModule {module}"
 
 
 def test_compile_cache_dir(monkeypatch, tmp_path):
