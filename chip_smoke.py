@@ -118,15 +118,19 @@ def _tree_bytes(tree) -> int:
 
 
 def memory_report(p, limit: int | None) -> None:
-    """Each compiled payload step with the rest of the resident train
-    state: arguments + outputs + temporaries - donated aliases."""
+    """Each compiled payload step with the rest of what stays resident
+    (the train state; for prefill and decode also the serving copy they
+    read): arguments + outputs + temporaries - donated aliases."""
     state = _tree_bytes(p.state)
-    reads = dict(train=state, prefill=_tree_bytes(p.state.params))
-    reads["decode"] = reads["prefill"]
-    print(f"resident train state {_gib(state)}")
+    serving = p.cast.memory_analysis().output_size_in_bytes
+    reads = dict(train=state, prefill=serving, decode=serving)
+    resident = dict(train=state, prefill=state + serving,
+                    decode=state + serving)
+    print(f"resident train state {_gib(state)}, serving copy "
+          f"{_gib(serving)}")
     for kind, c in p.compiled.items():
         m = c.memory_analysis()
-        total = (state + m.argument_size_in_bytes - reads[kind]
+        total = (resident[kind] + m.argument_size_in_bytes - reads[kind]
                  + m.output_size_in_bytes + m.temp_size_in_bytes
                  - m.alias_size_in_bytes)
         print(f"  {kind:8s} args {_gib(m.argument_size_in_bytes)}  "

@@ -12,6 +12,13 @@ step donates that state, so each payload dispatches under one lock and
 reads the current params there; the device runs dispatched steps in
 order, so a read dispatched before a donation completes before it.
 
+Rollouts and prefills read a serving copy of the params
+(``Model.serving_params``): the weights the steps would cast to bfloat16
+at every use, cast once.  One copy serves every decode and prefill step
+on one train-state version; it is made under the lock by the first such
+call after the state changes, and dropped when it does.  Without it each
+decode step re-casts the whole float32 tree and writes the copies out.
+
 Each payload call is a ``ddmd:<kind>`` span (``repro.core.tracing``); in
 it, ``ddmd:lock`` spans the wait for the lock (not the dispatch under it)
 and ``ddmd:block`` the wait for the device.  ``counters`` sums those two
@@ -76,6 +83,9 @@ class PayloadCounters:
     lock_wait_max_s: float = 0.0
     block_s: float = 0.0
     block_max_s: float = 0.0
+    #: serving copies made (one per train-state version that a rollout or
+    #: prefill read)
+    casts: int = 0
 
 
 class DDMDPayloads:
@@ -84,7 +94,13 @@ class DDMDPayloads:
 
     ``losses`` and ``logits_finite`` collect what each call produced, as
     device scalars, for the caller to check after a run; ``counters``
-    sums the calls' waits (a caller clears it by replacing it)."""
+    sums the calls' waits (a caller clears it by replacing it).
+
+    Prefill and decode run on the serving copy of ``state.params``, made
+    by ``cast`` (compiled ahead of time too, as ``jit_serving_params``).
+    ``state`` is a property so that assigning it, as a caller that
+    reseeds the state does, drops the copy of the old state; the copy
+    holds arrays of its own, so the old state is freed with it."""
 
     def __init__(self, model: Model, shapes: PayloadShapes = PayloadShapes()):
         self.model = model
@@ -103,13 +119,32 @@ class DDMDPayloads:
                                       s_max=s.cache_len)
         tok = jnp.zeros((s.decode_batch, 1), jnp.int32)
         pos = jnp.zeros((s.decode_batch,), jnp.int32)
+        self.cast = jax.jit(model.serving_params).lower(
+            self.state.params).compile()
+        serving = self.cast.out_info
         self.compiled = dict(
             train=train.lower(self.state, self._train_batch(0)).compile(),
-            prefill=prefill.lower(self.state.params,
-                                  self._prefill_batch(0)).compile(),
-            decode=decode.lower(self.state.params, self._cache(),
-                                tok, pos).compile(),
+            prefill=prefill.lower(serving, self._prefill_batch(0)).compile(),
+            decode=decode.lower(serving, self._cache(), tok, pos).compile(),
         )
+
+    @property
+    def state(self):
+        return self._state
+
+    @state.setter
+    def state(self, state) -> None:
+        self._state = state
+        self._serving = None
+
+    def _serving_params(self):
+        """The serving copy of the current params; call under the lock.
+        With none for this state, dispatch the cast first: the device
+        runs it after every step dispatched before it."""
+        if self._serving is None:
+            self._serving = self.cast(self.state.params)
+            self.counters.casts += 1
+        return self._serving
 
     def _train_batch(self, i: int):
         return self.model.make_batch(jax.random.PRNGKey(100 + i),
@@ -169,7 +204,7 @@ class DDMDPayloads:
                 pos = jnp.full((s.decode_batch,), t, jnp.int32)
                 with self._locked():
                     nxt, logits, cache = self.compiled["decode"](
-                        self.state.params, cache, tok, pos)
+                        self._serving_params(), cache, tok, pos)
                 finite &= jnp.isfinite(logits).all()
                 tok = nxt[:, None]
             self.logits_finite.append(finite)
@@ -184,6 +219,8 @@ class DDMDPayloads:
         with span("ddmd:training"):
             batch = self._train_batch(i)
             with self._locked():
+                # the old copy is freed once the steps queued on it ran
+                self._serving = None
                 self.state, metrics = self.compiled["train"](self.state,
                                                              batch)
             self.losses.append(metrics["loss"])
@@ -193,7 +230,8 @@ class DDMDPayloads:
         with span("ddmd:inference"):
             batch = self._prefill_batch(i)
             with self._locked():
-                logits = self.compiled["prefill"](self.state.params, batch)
+                logits = self.compiled["prefill"](self._serving_params(),
+                                                  batch)
             self.logits_finite.append(jnp.isfinite(logits).all())
             return self._block(logits)
 

@@ -10,6 +10,8 @@ talks to this interface only:
     m.cache_specs(batch, s_max)       -> ParamSpec pytree (decode state)
     m.decode_step(params, cache, tokens, pos) -> (logits [B, V], cache)
     m.input_specs(shape)              -> ShapeDtypeStruct batch stand-ins
+    m.serving_params(params)          -> params for forward / decode_step,
+                                         weights pre-cast to COMPUTE_DTYPE
 """
 
 from __future__ import annotations
@@ -36,6 +38,7 @@ class Model:
     _forward: Callable
     _cache_specs: Callable | None
     _decode: Callable | None
+    _serving: Callable | None = None
 
     def specs(self):
         return self._specs(self.cfg)
@@ -53,6 +56,15 @@ class Model:
 
     def decode_step(self, params, cache, tokens, pos):
         return self._decode(params, cache, tokens, pos, self.cfg)
+
+    def serving_params(self, params):
+        """``params`` with the leaves that ``forward`` and ``decode_step``
+        cast to COMPUTE_DTYPE at every use cast once, for a caller that
+        runs many steps on one version of the weights; same outputs.  A
+        family that declares no such leaves gets ``params`` back."""
+        if self._serving is None:
+            return params
+        return self._serving(params, self.cfg)
 
     # -- batch stand-ins -----------------------------------------------------
     def input_specs(self, *, batch: int, seq: int, mode: str = "train"):
@@ -110,7 +122,7 @@ def build_model(cfg: ModelConfig) -> Model:
     if cfg.family in ("dense", "moe", "vlm"):
         return Model(cfg, tf_model.transformer_specs, tf_model.loss_fn,
                      tf_model.forward, tf_model.init_cache_specs,
-                     tf_model.decode_step)
+                     tf_model.decode_step, tf_model.serving_params)
     if cfg.family == "ssm" and cfg.rwkv:
         return Model(cfg, rwkv_model.rwkv6_specs, rwkv_model.loss_fn,
                      rwkv_model.forward, rwkv_model.init_cache_specs,

@@ -43,6 +43,27 @@ def transformer_specs(cfg: ModelConfig):
     }
 
 
+#: leaves that ``forward`` and ``decode_step`` use only cast to
+#: COMPUTE_DTYPE (the norm scales are used in float32, the QKV biases are
+#: cast in-step and tiny, and MoE routes in float32)
+_SERVING_CAST = {"attn": ("wq", "wk", "wv", "wo"),
+                 "mlp": ("gate", "up", "down")}
+
+
+def serving_params(params, cfg: ModelConfig):
+    """``params`` with every leaf in ``_SERVING_CAST``, the embedding and
+    the untied head cast to COMPUTE_DTYPE, the rest as they are.  The
+    steps' own casts of these leaves are then no-ops, so ``forward`` and
+    ``decode_step`` give the same numbers on either tree."""
+    def cast(tree, names):
+        return {k: v.astype(COMPUTE_DTYPE) if k in names else v
+                for k, v in tree.items()}
+
+    blocks = {k: cast(v, _SERVING_CAST[k]) if k in _SERVING_CAST else v
+              for k, v in params["blocks"].items()}
+    return {**cast(params, ("embedding", "lm_head")), "blocks": blocks}
+
+
 def _layer_params(p, idx):
     """Slice one layer's parameters out of the stacked tree."""
     return jax.tree.map(lambda a: a[idx], p)

@@ -232,6 +232,7 @@ def test_ddmd_real_payloads(ddmd_payloads, mode):
     p = ddmd_payloads
     n_loss, n_logits = len(p.losses), len(p.logits_finite)
     p.counters = ddmd.PayloadCounters()
+    p.state = p.state              # as a reseed does: drops the copy
     res, n_tasks = ddmd.run(p, mode)
     assert res.tasks_total == n_tasks == 48
     assert len(p.losses) - n_loss == 3
@@ -243,6 +244,36 @@ def test_ddmd_real_payloads(ddmd_payloads, mode):
     assert c.lock_acquires == 6 * 3 * p.shapes.decode_steps + 3 + 18
     assert 0.0 <= c.lock_wait_max_s <= c.lock_wait_s
     assert 0.0 < c.block_max_s <= c.block_s
+    # a serving copy for the state the run starts from, then one after
+    # each train step; async mode may run two train steps with no rollout
+    # or prefill between them, and the last one is always read
+    if mode == "sequential":
+        assert c.casts == 1 + 3
+    else:
+        assert 2 <= c.casts <= 1 + 3
+
+
+def test_ddmd_serving_copy_follows_state(ddmd_payloads):
+    """Prefill and decode share one serving copy per train-state version:
+    replacing ``state`` (``None`` first, as a reseed does) or a train step
+    drops it, and the next call makes one."""
+    from repro.launch import ddmd
+    from repro.runtime.steps import make_train_state
+    p = ddmd_payloads
+    p.state = None
+    p.state = make_train_state(p.model, jax.random.PRNGKey(1))
+    p.counters = ddmd.PayloadCounters()
+    p.inference(0)
+    p.simulation(0)
+    assert p.counters.casts == 1
+    p.training(0)
+    p.inference(1)
+    assert p.counters.casts == 2
+    p.state = None
+    p.state = make_train_state(p.model, jax.random.PRNGKey(2))
+    p.simulation(1)
+    p.inference(2)
+    assert p.counters.casts == 3
 
 
 @pytest.mark.parametrize("step,module", [("train", "jit_train_step"),
@@ -253,6 +284,13 @@ def test_step_program_names(ddmd_payloads, step, module):
     the benchmark's per-layer readers find the steps by these names."""
     text = ddmd_payloads.compiled[step].as_text()
     assert text.split(",", 1)[0] == f"HloModule {module}"
+
+
+def test_cast_program_name(ddmd_payloads):
+    """The serving copy's cast is a program of its own, which none of the
+    readers of the three steps' device time count."""
+    text = ddmd_payloads.cast.as_text()
+    assert text.split(",", 1)[0] == "HloModule jit_serving_params"
 
 
 def test_compile_cache_dir(monkeypatch, tmp_path):
