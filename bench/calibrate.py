@@ -65,8 +65,7 @@ def calibrate(cell: dict, seeds: list[int], control: set, seconds: float,
 
     import numpy as np
 
-    from bench import check, device, harness
-    from bench.run import program_config
+    from bench import arch, check, device, harness
     from repro.models.api import build_model
 
     cfg, traffic, limits = cell["cfg"], cell["traffic"], cell["limits"]
@@ -78,7 +77,7 @@ def calibrate(cell: dict, seeds: list[int], control: set, seconds: float,
     if require_chip:
         device.require(cell["cell"]["chips"])
     wl = harness.Workload(cfg, traffic, seeds[0],
-                          build_model(program_config(cfg)))
+                          build_model(arch.of(cfg).program_config(cfg)))
     for n, seed in enumerate(seeds):
         if n:
             wl.reseed(seed)

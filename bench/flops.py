@@ -1,4 +1,7 @@
-"""Operations and bytes that the work needs, counted from shapes.
+"""Operations and bytes that the work needs, counted from shapes: the
+counting that architectures share.  Each architecture module
+(``bench/arch``) counts its own model FLOPs and names its kernels' costs
+with these.
 
 Model FLOPs count the multiply-adds of the algorithm once (two FLOPs
 each): the parameter matmuls and the attention products.  A training
@@ -18,52 +21,21 @@ from __future__ import annotations
 BF16 = 2
 
 
-def matmul_params(cfg: dict) -> tuple[int, int]:
-    """(parameters in the blocks' matmuls, parameters of the output
-    head's matmul)."""
-    d = cfg["hidden_size"]
-    q = cfg["num_attention_heads"] * cfg["head_dim"]
-    kv = cfg["num_key_value_heads"] * cfg["head_dim"]
-    f = cfg["intermediate_size"]
-    per_layer = d * q + 2 * d * kv + q * d + 3 * d * f
-    return cfg["num_hidden_layers"] * per_layer, d * cfg["vocab_size"]
-
-
-def _window(cfg: dict, ctx: int) -> int:
+def window(cfg: dict, ctx: int) -> int:
+    """Positions a query sees among ``ctx``, under a sliding window."""
     w = cfg.get("sliding_window")
     return min(ctx, w) if w else ctx
 
 
+def mean_context(shapes: dict) -> float:
+    """Mean cached positions a decode step attends to: a rollout's step t
+    (from 0) sees t + 1."""
+    return (shapes["decode_steps"] + 1) / 2
+
+
 def attended_pairs(cfg: dict, seq: int) -> int:
     """(query, key) pairs a causal, possibly windowed, sequence scores."""
-    return sum(_window(cfg, t + 1) for t in range(seq))
-
-
-def _attn_flops(cfg: dict, pairs: int) -> int:
-    # QK^T and PV, per query head and layer
-    return (4 * cfg["num_attention_heads"] * cfg["head_dim"] * pairs
-            * cfg["num_hidden_layers"])
-
-
-def train_flops(cfg: dict, batch: int, seq: int) -> int:
-    blocks, head = matmul_params(cfg)
-    fwd = (2 * (blocks + head) * batch * seq
-           + _attn_flops(cfg, batch * attended_pairs(cfg, seq)))
-    return 3 * fwd
-
-
-def prefill_flops(cfg: dict, batch: int, seq: int) -> int:
-    blocks, head = matmul_params(cfg)
-    return (2 * blocks * batch * seq + 2 * head * batch
-            + _attn_flops(cfg, batch * attended_pairs(cfg, seq)))
-
-
-def decode_flops(cfg: dict, batch: int, ctx: float) -> float:
-    """One token per row against ``ctx`` cached positions (the new one
-    included)."""
-    blocks, head = matmul_params(cfg)
-    return (2 * (blocks + head) * batch
-            + _attn_flops(cfg, batch * _window(cfg, ctx)))
+    return sum(window(cfg, t + 1) for t in range(seq))
 
 
 def flash_cost(cfg: dict, batch: int, seq: int) -> tuple[float, float]:
@@ -82,7 +54,7 @@ def decode_attn_cost(cfg: dict, batch: int, ctx: float) -> tuple[float, float]:
     row against ``ctx`` written cache positions."""
     h, kvh, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
                   cfg["head_dim"])
-    live = _window(cfg, ctx)
+    live = window(cfg, ctx)
     flops = 4 * h * hd * batch * live
     q_o = 2 * batch * h * hd
     k_v = 2 * batch * live * kvh * hd

@@ -12,6 +12,9 @@ it can see what each call read and produced:
 - for the calls the check samples, the compiled prefill and decode steps
   keep their outputs and inputs.
 
+Each instance starts with the payloads' counters cleared and reports
+them; with ``perf`` the executor counts its own (``PerfCounters``) too.
+
 A traffic file (``bench/traffic/<name>.json``) gives the DAG: task sets
 per iteration with their payload, task count and (cpus, gpus), edges with
 ``{i}`` and ``{i+1}`` placeholders, the pool, and the payload shapes.
@@ -139,6 +142,8 @@ class Workload:
         self.record: Record | None = None
         self.sampled: set = set()
         self.warm_up = False
+        #: the compiled steps as the program made them
+        self.steps = dict(self.payloads.compiled)
         self._wrap_compiled()
         self.reseed(seed)
 
@@ -172,6 +177,11 @@ class Workload:
         self.payloads.state = None
         self.payloads.state = jax.block_until_ready(self._fresh(self._key))
         self.version = 0
+
+    def hlo_texts(self) -> list[str]:
+        """The compiled HLO text of every program the payloads run."""
+        return [c.as_text() for c in (*self.steps.values(),
+                                      self.payloads.cast)]
 
     # -- wrappers ------------------------------------------------------------
     def _ctx(self):
@@ -252,9 +262,11 @@ class Workload:
             out.update(self.rng.sample(tasks, min(n, len(tasks))))
         return out
 
-    def run_instance(self, inst: int):
-        """One whole instance; returns (start, end, ExecResult, Record)."""
-        from repro.core import RealExecutor
+    def run_instance(self, inst: int, perf: bool = False):
+        """One whole instance; returns (start, end, Record, counters):
+        ``payload``, the payloads' counters, and with ``perf`` also
+        ``perf``, the executor's, as dicts."""
+        from repro.core import RealExecutor, RunConfig
 
         g = instance_dag(self.traffic,
                          lambda kind, name, k: self.task(inst, kind, name, k))
@@ -263,16 +275,21 @@ class Workload:
         # the program's own per-call lists, which the check does not read
         self.payloads.losses.clear()
         self.payloads.logits_finite.clear()
+        self.payloads.counters = type(self.payloads.counters)()
         t0 = time.perf_counter()
-        res = RealExecutor(self.pool, launch_latency=0.0).run(g, "async")
+        res = RealExecutor(self.pool, launch_latency=0.0).run(
+            g, "async", config=RunConfig(perf_counters=perf))
         t1 = time.perf_counter()
+        counters = dict(payload=dataclasses.asdict(self.payloads.counters))
+        if perf:
+            counters["perf"] = dataclasses.asdict(res.perf)
         rec = self.record
         if self.warm_up:
             rec.change_norms = self._change(self.payloads.state.params,
                                             self._key)
         self.record = None
         self.runs.append((inst, g))
-        return t0, t1, res, g, rec
+        return t0, t1, rec, counters
 
     def warm(self) -> Record:
         """The set-up's whole instance: it warms the executor and every
@@ -280,7 +297,7 @@ class Workload:
         follows its train steps."""
         self.warm_up = True
         try:
-            return self.run_instance(0)[4]
+            return self.run_instance(0)[2]
         finally:
             self.warm_up = False
 
@@ -288,13 +305,15 @@ class Workload:
                profile_options=None) -> dict:
         """Whole instances back to back for ``seconds``; with
         ``trace_dir`` the profiler records the first one, under the
-        ``bench:window`` annotation.  Returns the window's arithmetic and
-        the record the check keeps: one counted instance, drawn from the
-        seed by a reservoir of one."""
+        ``bench:window`` annotation, and every instance counts the
+        executor's ``PerfCounters``.  Returns the window's arithmetic,
+        each instance's counters, and the record the check keeps: one
+        counted instance, drawn from the seed by a reservoir of one."""
         from bench.trace import WINDOW
 
         pick = random.Random(self.seed ^ 0x5EED)
-        kept, counted, spans = None, 0, []
+        kept, counted, spans, counters = None, 0, [], []
+        perf = trace_dir is not None
         deadline = time.perf_counter() + seconds
         inst = 1
         while time.perf_counter() < deadline:
@@ -303,11 +322,12 @@ class Workload:
                 jax.profiler.start_trace(trace_dir,
                                          profiler_options=profile_options)
                 with jax.profiler.TraceAnnotation(WINDOW):
-                    s, e, _res, _g, rec = self.run_instance(inst)
+                    s, e, rec, c = self.run_instance(inst, perf)
                 jax.profiler.stop_trace()
             else:
-                s, e, _res, _g, rec = self.run_instance(inst)
+                s, e, rec, c = self.run_instance(inst, perf)
             spans.append((s, e, self.per_inst))
+            counters.append(c)
             if e <= deadline:
                 counted += 1
                 if pick.random() < 1.0 / counted:
@@ -315,4 +335,4 @@ class Workload:
             inst += 1
         return dict(metrics=window_metrics(spans, deadline), kept=kept,
                     attempted=len(spans) * self.per_inst,
-                    walls=[e - s for s, e, _ in spans])
+                    walls=[e - s for s, e, _ in spans], counters=counters)

@@ -14,9 +14,9 @@ interpreter's garbage collections (count, summed and longest pause per
 generation), which hold every thread while they run, and its longest
 payload calls (seconds, start after the instance's, task).  The first
 ``--traced`` instances run under the profiler, each in a trace of its
-own, reduced by ``bench/trace.py`` (busy, idle stretches by payload) and
-``bench/spans.py`` (idle stretches named by the program's spans, and the
-spans' three numbers).  No check runs: this is a diagnostic, not a cell.
+own, reduced by ``bench/trace.py`` (busy, idle stretches by payload and
+by the program's spans) and read by ``bench/spans.py`` (the spans' four
+numbers).  No check runs: this is a diagnostic, not a cell.
 
 The whole record goes to ``--out``; the last line of stdout is a summary
 with every instance longer than the median by more than ``STALL_S``.
@@ -30,7 +30,6 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
-import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import glob  # noqa: E402
 import json  # noqa: E402
@@ -68,35 +67,22 @@ class GcPauses:
 
 
 def instance(wl, inst: int) -> dict:
-    """One whole instance with the executor's and the payloads' counters
-    on; returns its wall, both counter records and its collections."""
-    from repro.core import RealExecutor, RunConfig
-    from repro.launch import ddmd
-
-    from bench import harness
-
-    g = harness.instance_dag(
-        wl.traffic, lambda kind, name, k: wl.task(inst, kind, name, k))
-    wl.payloads.counters = ddmd.PayloadCounters()
+    """One whole instance with the executor's counters on; returns its
+    wall, both counter records, its collections and its longest calls."""
     pauses = GcPauses()
     gc.callbacks.append(pauses)
     try:
-        t0 = time.perf_counter()
-        res = RealExecutor(wl.pool, launch_latency=0.0).run(
-            g, "async", config=RunConfig(perf_counters=True))
-        wall = time.perf_counter() - t0
+        t0, t1, _, counters = wl.run_instance(inst, perf=True)
     finally:
         gc.callbacks.remove(pauses)
     calls = sorted(((c.end - c.start, c.start - t0, f"{c.set}[{c.i}]")
                     for c in wl.spans if c.inst == inst), reverse=True)
-    return dict(inst=inst, wall_s=wall,
-                perf=dataclasses.asdict(res.perf),
-                payload=dataclasses.asdict(wl.payloads.counters),
-                gc=pauses.by_gen, longest_calls=calls[:LONGEST])
+    return dict(inst=inst, wall_s=t1 - t0, **counters, gc=pauses.by_gen,
+                longest_calls=calls[:LONGEST])
 
 
 def traced(wl, inst: int, options) -> dict:
-    """``instance`` under the profiler, with both reductions of its trace."""
+    """``instance`` under the profiler, with its trace reduced."""
     import jax
 
     from bench import spans, trace
@@ -109,21 +95,20 @@ def traced(wl, inst: int, options) -> dict:
         jax.profiler.stop_trace()
         pb = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[0]
         r = trace.reduce(pb)
-        s = spans.reduce(pb)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
     named = [[name, ns / 1e9, {k: v / 1e9 for k, v in parts.items()}]
-             for name, ns, parts in s["named_gaps"]]
+             for name, ns, parts in r.span_gaps]
     out["trace"] = dict(
         busy_s=r.busy_ns / 1e9, window_s=r.window_ns / 1e9,
         idle_s={k: v / 1e9 for k, v in r.idle_ns.items()},
         idle_gaps=[[k, v / 1e9] for k, v in r.gaps],
         span_gaps=named,
-        spans=len(s["spans"]),
-        engine_ms_per_task=s["engine_ms_per_task"],
-        task_handoff_ms=s["task_handoff_ms"],
-        lock_wait_share=s["lock_wait_share"],
-        wait_timeouts=s["wait_timeouts"])
+        spans=len(r.spans),
+        engine_ms_per_task=spans.engine_ms_per_task(r.spans),
+        task_handoff_ms=spans.task_handoff_ms(r.spans),
+        lock_wait_share=spans.lock_wait_share(r.spans),
+        wait_timeouts=spans.wait_timeouts(r.spans))
     return out
 
 
@@ -134,7 +119,7 @@ def run(name: str, seed: int, seconds: float, n_traced: int, *,
     ``require_chip`` as in ``bench.run.run``."""
     import jax
 
-    from bench import device, harness
+    from bench import arch, device, harness
     from bench import run as bench_run
     from repro.models.api import build_model
 
@@ -142,7 +127,7 @@ def run(name: str, seed: int, seconds: float, n_traced: int, *,
     cell = cell or bench_run.load_cell(name)
     chips = cell["cell"]["chips"]
     devs = device.require(chips) if require_chip else jax.devices()[:chips]
-    model = build_model(bench_run.program_config(cell["cfg"], log))
+    model = build_model(arch.of(cell["cfg"]).program_config(cell["cfg"], log))
     wl = harness.Workload(cell["cfg"], cell["traffic"], seed, model)
     wl.warm()
     setup_s = time.perf_counter() - T_PROCESS

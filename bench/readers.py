@@ -7,19 +7,23 @@ from __future__ import annotations
 
 import dataclasses
 
-from bench import flops
+from bench import arch, flops
+from bench.arch import DECODE, PREFILL, TRAIN  # noqa: F401 (the readers)
 from bench.trace import PAYLOAD, Reduced
-
-#: the program's jitted steps, by the name the trace gives their runs
-TRAIN, PREFILL, DECODE = "jit_train_step", "jit_prefill", "jit_decode"
 
 
 @dataclasses.dataclass
 class Context:
+    """What a reader reads: the reduced trace of the traced instance
+    (device time by program, kernel and named scope; the program's host
+    spans), the cell's configuration and traffic files, the chip's peaks,
+    and the traced instance's counters: ``perf``, the executor's
+    ``PerfCounters``, and ``payload``, the payloads' counters, as dicts."""
     trace: Reduced
     cfg: dict
     traffic: dict
     peaks: dict
+    counters: dict
 
 
 def idle_share(ctx: Context, dispatch: bool) -> float:
@@ -39,27 +43,11 @@ def step_ms(ctx: Context, program: str) -> float | None:
     return runs[1] / runs[0] / 1e6
 
 
-def mean_context(traffic: dict) -> float:
-    """Mean cached positions a decode step attends to: a rollout's step t
-    (from 0) sees t + 1."""
-    return (traffic["shapes"]["decode_steps"] + 1) / 2
-
-
-def step_flops(ctx: Context) -> dict:
-    sh, cfg = ctx.traffic["shapes"], ctx.cfg
-    return {
-        TRAIN: flops.train_flops(cfg, sh["train_batch"], sh["train_seq"]),
-        PREFILL: flops.prefill_flops(cfg, sh["prefill_batch"],
-                                     sh["prefill_seq"]),
-        DECODE: flops.decode_flops(cfg, sh["decode_batch"],
-                                   mean_context(ctx.traffic)),
-    }
-
-
 def step_mfu(ctx: Context) -> float | None:
     """Percent of bf16 peak over the steps' own device time."""
     work = secs = 0.0
-    for prog, f in step_flops(ctx).items():
+    model = arch.of(ctx.cfg).step_flops(ctx.cfg, ctx.traffic["shapes"])
+    for prog, f in model.items():
         runs = ctx.trace.programs.get(prog)
         if runs:
             work += runs[0] * f
@@ -69,33 +57,21 @@ def step_mfu(ctx: Context) -> float | None:
     return 100.0 * work / (secs * ctx.peaks["bf16_flops"])
 
 
-def kernel_shapes(ctx: Context, kernel: str) -> dict:
-    """program -> (FLOPs, bytes) of one call of ``kernel`` in it."""
-    sh, cfg = ctx.traffic["shapes"], ctx.cfg
-    if kernel == "flash_attention_pallas":
-        return {TRAIN: flops.flash_cost(cfg, sh["train_batch"],
-                                        sh["train_seq"]),
-                PREFILL: flops.flash_cost(cfg, sh["prefill_batch"],
-                                          sh["prefill_seq"])}
-    if kernel == "decode_attention_pallas":
-        return {DECODE: flops.decode_attn_cost(cfg, sh["decode_batch"],
-                                               mean_context(ctx.traffic))}
-    raise KeyError(kernel)
-
-
 def roofline(ctx: Context, kernel: str) -> float | None:
     """Percent: the roofline's least time for every call of ``kernel`` in
     the window over the calls' summed device time.  None where the kernel
-    did not run, or ran inside a program whose shapes are not known."""
+    did not run, or ran inside a program for which the architecture
+    module gives no cost."""
     calls = ctx.trace.kernels.get(kernel)
     if not calls:
         return None
-    shapes = kernel_shapes(ctx, kernel)
-    if set(calls) - set(shapes):
+    costs = arch.of(ctx.cfg).kernel_costs(
+        ctx.cfg, ctx.traffic["shapes"]).get(kernel, {})
+    if set(calls) - set(costs):
         return None
     least = spent = 0.0
     for prog, (n, ns) in calls.items():
-        t, _ = flops.least_seconds(*shapes[prog], ctx.peaks)
+        t, _ = flops.least_seconds(*costs[prog], ctx.peaks)
         least += n * t
         spent += ns / 1e9
     return 100.0 * least / spent

@@ -12,9 +12,11 @@ payloads (their steps compile, or load from the compile cache in
 ``.jax_cache/`` of the checkout), make the state from the seed, and run
 one whole instance, which also warms every small program the payloads
 dispatch.  Then whole instances run back to back for ``--seconds``.
-With ``--trace 1`` the profiler records the window's first instance and
-the run reports the per-layer metrics read from that trace; otherwise
-the end-to-end metrics.  Last, the
+With ``--trace 1`` the profiler records the window's first instance, the
+executor counts its own work (``PerfCounters``), and the run reports the
+per-layer metrics read from that trace, the programs' HLO text (kept at
+set-up) and that instance's counters; otherwise the end-to-end metrics.
+The result's ``window`` holds each instance's counters.  Last, the
 check: the executor's schedule, and what the timed path produced against
 the plain reference (``bench/check.py``), run once the program's state
 is freed.
@@ -73,40 +75,6 @@ def load_cell(name: str, root: Path = ROOT) -> dict:
     )
 
 
-def program_config(cfg: dict, log=None):
-    """The program's ModelConfig for a configuration file.  Where the
-    program's registry has the model, ``log`` reports each field, not
-    listed under ``reduced``, on which the registry differs: the file's
-    value is the one that runs."""
-    import dataclasses
-
-    from repro.models.config import ModelConfig
-
-    mc = ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg["head_dim"], qkv_bias=cfg["attention_bias"],
-        sliding_window=cfg["sliding_window"], rope_theta=cfg["rope_theta"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        norm_eps=cfg["rms_norm_eps"])
-    if cfg.get("program_arch"):
-        from repro.configs import get_config
-
-        theirs = dataclasses.replace(get_config(cfg["program_arch"]),
-                                     name=mc.name)
-        if "num_hidden_layers" in cfg.get("reduced", {}):
-            theirs = dataclasses.replace(theirs, num_layers=mc.num_layers)
-        for f in dataclasses.fields(mc):
-            ours, reg = getattr(mc, f.name), getattr(theirs, f.name)
-            if ours != reg and log:
-                log(f"{cfg['name']}: the program's registry has {f.name} "
-                    f"{reg!r}; the file's {ours!r} runs")
-    return mc
-
-
 def _reader(name: str):
     path = BENCH / "metrics" / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
@@ -134,7 +102,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     False skips only the look for a TPU."""
     import jax
 
-    from bench import check, device, harness, trace as tr
+    from bench import arch, check, device, harness, trace as tr
     from bench.readers import Context
     from repro.models.api import build_model
 
@@ -145,10 +113,12 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     devs = device.require(chips) if require_chip else jax.devices()[:chips]
     peaks = device.PEAKS.get(devs[0].device_kind)
 
-    model = build_model(program_config(cfg, log))
+    model = build_model(arch.of(cfg).program_config(cfg, log))
     wl = harness.Workload(cfg, traffic, seed, model)
     warm_record = wl.warm()
     setup_s = time.perf_counter() - T_PROCESS
+    # the traced run's set-up: after ``setup_s``, before the window
+    hlo_texts = wl.hlo_texts() if trace else ()
 
     # programs made inside the window: compiled, or loaded from the cache
     compiles = []
@@ -168,9 +138,10 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
                         device=dev_info)
     if trace:
         pb = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
-        reduced = tr.reduce(pb[0])
+        reduced = tr.reduce(pb[0], hlo_texts)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        ctx = Context(trace=reduced, cfg=cfg, traffic=traffic, peaks=peaks)
+        ctx = Context(trace=reduced, cfg=cfg, traffic=traffic, peaks=peaks,
+                      counters=win["counters"][0])
         for m in cell["per_layer"]:
             v = _reader(m["name"])(ctx)
             if v is not None:
@@ -198,7 +169,8 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     result["correct"] = (wm["instances"] > 0 and check.verdict(
         {k: numbers[k] for k in limits}, limits))
     result["window"] = dict(wm, compiles_in_window=in_window,
-                            instance_walls=win["walls"])
+                            instance_walls=win["walls"],
+                            counters=win["counters"])
     result["checks"] = shown
     for k, v in shown.items():
         log(f"check {k} = {v['value']!r} (limit {v['limit']!r})")

@@ -8,33 +8,38 @@ it, ``ddmd:lock`` (the wait for the state's lock) and ``ddmd:block`` (the
 wait for the device).  Each host thread is one line of the trace's host
 plane, and spans on one line nest.
 
+A program's span is any host annotation named in the program's form, a
+lowercase ``<prefix>:`` and then a name, except the harness's own
+(``bench:``, ``payload:``); JAX's own host events (``PjitFunction(f)``,
+``Foo::Bar``) have another form.
+
 An idle stretch of the device is named by what the threads were in
 during it.  At each moment every thread is in its innermost open span,
 and the moment takes the name that comes first in ``PRECEDENCE``: a
 payload's own host work, then the dispatcher's work, then a worker
-outside its payload, then the waits (lock, device, dispatcher); ``none``
-where no thread is in a span.  A stretch is named by the moment's name
-that covers most of it.
+outside its payload, then any other program span (taken as host work),
+then the waits (lock, device, dispatcher); ``none`` where no thread is
+in a span.  A stretch is named by the moment's name that covers most of
+it.
 
-``reduce`` gives, from one trace: the spans inside ``bench:window``, the
-ten longest idle stretches so named, three numbers that read the spans
-(``engine_ms_per_task``, ``task_handoff_ms``, ``lock_wait_share``) and
-the count of dispatcher waits that timed out.  ``bench/hunt.py`` prints them; the per-layer
-readers of ``bench/run.py`` do not read them (PERF.md, Open questions).
+``bench/trace.py`` keeps the spans inside ``bench:window`` and the ten
+longest idle stretches so named; the functions below read numbers from
+the spans for ``bench/metrics`` and ``bench/hunt.py``.
 """
 
 from __future__ import annotations
 
 import collections
+import re
 from typing import NamedTuple
 
-from bench.trace import WINDOW, _clip, _union
-
-PROGRAM = ("exec:", "ddmd:")
+_PROGRAM = re.compile(r"[a-z][a-z0-9_]*:[^:\s]")
+_HARNESS = ("bench:", "payload:")
 #: innermost-span names, strongest first; ``ddmd:<kind>`` stands for any
-#: ``ddmd:`` span that is not a lock or block wait
+#: ``ddmd:`` span that is not a lock or block wait, ``<other>`` for any
+#: span not named here
 PRECEDENCE = ("ddmd:<kind>", "exec:pass", "exec:predict", "exec:task",
-              "ddmd:lock", "ddmd:block", "exec:wait")
+              "<other>", "ddmd:lock", "ddmd:block", "exec:wait")
 WAITS = ("ddmd:lock", "ddmd:block")
 
 
@@ -49,7 +54,14 @@ class HostSpan(NamedTuple):
 def _rank(name: str) -> int:
     if name.startswith("ddmd:") and name not in WAITS:
         return 0
-    return PRECEDENCE.index(name)
+    if name in PRECEDENCE:
+        return PRECEDENCE.index(name)
+    return PRECEDENCE.index("<other>")
+
+
+def is_program_span(name: str) -> bool:
+    return (_PROGRAM.match(name) is not None
+            and not name.startswith(_HARNESS))
 
 
 def program_spans(plane, lo: float, hi: float) -> list[HostSpan]:
@@ -57,7 +69,7 @@ def program_spans(plane, lo: float, hi: float) -> list[HostSpan]:
     out = []
     for thread, line in enumerate(plane.lines):
         for e in line.events:
-            if e.name.startswith(PROGRAM):
+            if is_program_span(e.name):
                 s, d = e.start_ns, e.start_ns + e.duration_ns
                 if d > lo and s < hi:
                     out.append(HostSpan(s, d, e.name, thread,
@@ -150,34 +162,3 @@ def lock_wait_share(spans: list[HostSpan]) -> float | None:
     if not calls:
         return None
     return 100.0 * _total(spans, lambda n: n == "ddmd:lock") / calls
-
-
-def device_busy(plane, lo: float, hi: float) -> list:
-    """The union of the device's operations inside ``[lo, hi)``, as
-    ``bench/trace.py`` computes it for ``busy_ns``."""
-    ops = [ln for ln in plane.lines if ln.name == "XLA Ops"][0]
-    return _clip(_union((e.start_ns, e.start_ns + e.duration_ns)
-                        for e in ops.events
-                        if lo < e.start_ns + e.duration_ns
-                        and e.start_ns < hi), lo, hi)
-
-
-def reduce(path: str, device: str = "/device:TPU:0") -> dict:
-    from jax.profiler import ProfileData
-
-    data = ProfileData.from_file(path)
-    host = data.find_plane_with_name("/host:CPU")
-    window = next(((e.start_ns, e.start_ns + e.duration_ns)
-                   for line in host.lines for e in line.events
-                   if e.name == WINDOW), None)
-    if window is None:
-        raise ValueError(f"{path}: no {WINDOW} annotation")
-    spans = program_spans(host, *window)
-    busy = device_busy(data.find_plane_with_name(device), *window)
-    return dict(
-        spans=spans,
-        named_gaps=named_gaps(busy, window, spans),
-        engine_ms_per_task=engine_ms_per_task(spans),
-        task_handoff_ms=task_handoff_ms(spans),
-        lock_wait_share=lock_wait_share(spans),
-        wait_timeouts=wait_timeouts(spans))

@@ -1,8 +1,10 @@
-"""The FLOP and byte functions against counts made by hand."""
+"""The FLOP and byte functions against counts made by hand: the shared
+counting of ``bench/flops.py`` and the decoder's model FLOPs."""
 
 import pytest
 
 from bench import flops
+from bench.arch import decoder
 
 #: one layer, d 4, ffn 8, 2 query heads and 1 KV head of width 2, 10 words
 CFG = dict(hidden_size=4, intermediate_size=8, num_hidden_layers=1,
@@ -12,7 +14,7 @@ CFG = dict(hidden_size=4, intermediate_size=8, num_hidden_layers=1,
 
 def test_matmul_params():
     # wq 4x4, wk 4x2, wv 4x2, wo 4x4, gate/up 4x8, down 8x4; head 4x10
-    assert flops.matmul_params(CFG) == (16 + 8 + 8 + 16 + 96, 40)
+    assert decoder.matmul_params(CFG) == (16 + 8 + 8 + 16 + 96, 40)
 
 
 def test_attended_pairs_causal_and_windowed():
@@ -22,11 +24,11 @@ def test_attended_pairs_causal_and_windowed():
 
 def test_train_prefill_decode_flops():
     attn = 4 * 2 * 2 * 6            # QK^T and PV: 4 * heads * width * pairs
-    assert flops.train_flops(CFG, 1, 3) == 3 * (2 * 184 * 3 + attn)
+    assert decoder.train_flops(CFG, 1, 3) == 3 * (2 * 184 * 3 + attn)
     # prefill unembeds each row's last position only
-    assert flops.prefill_flops(CFG, 1, 3) == 2 * 144 * 3 + 2 * 40 + attn
+    assert decoder.prefill_flops(CFG, 1, 3) == 2 * 144 * 3 + 2 * 40 + attn
     # two rows, each attending to three cached positions
-    assert flops.decode_flops(CFG, 2, 3) == 2 * 184 * 2 + 4 * 2 * 2 * 6
+    assert decoder.decode_flops(CFG, 2, 3) == 2 * 184 * 2 + 4 * 2 * 2 * 6
 
 
 def test_kernel_costs():

@@ -47,6 +47,25 @@ def test_precedence_when_threads_disagree(a, b, name):
     assert spans.named_gaps([], (0, 10), sp) == [(name, 10, {name: 10})]
 
 
+@pytest.mark.parametrize("name,program", [
+    ("exec:pass", True), ("ddmd:simulation", True), ("demo:step", True),
+    ("moe_layer:route", True), ("bench:window", False),
+    ("payload:simulation", False), ("PjitFunction(decode)", False),
+    ("Foo::Bar", False), ("tsl::profiler", False), ("ExecuteOnLocalDevices", False),
+])
+def test_program_spans_have_the_programs_form(name, program):
+    assert spans.is_program_span(name) is program
+
+
+def test_other_program_spans_rank_as_host_work():
+    sp = [_span("moe:route", 0, 10, 0), _span("ddmd:lock", 0, 10, 1),
+          _span("exec:task", 0, 10, 2)]
+    assert spans.named_gaps([], (0, 10), sp[:2]) == [
+        ("moe:route", 10, {"moe:route": 10})]
+    assert spans.named_gaps([], (0, 10), sp[1:] + sp[:1]) == [
+        ("exec:task", 10, {"exec:task": 10})]
+
+
 def test_no_open_span_is_none():
     sp = [_span("exec:wait", 0, 4, 0)]
     assert spans.named_gaps([(6, 10)], (0, 10), sp) == [
@@ -73,16 +92,15 @@ def test_numbers_read_from_spans():
 def test_small_trace_has_no_program_spans():
     """The recorded trace predates the program's spans: the numbers read
     nothing, every idle stretch is ``none``, and the stretches are the
-    ones ``bench/trace.py`` finds."""
-    r = spans.reduce(str(SMALL))
-    assert r["spans"] == []
-    assert r["engine_ms_per_task"] is None
-    assert r["task_handoff_ms"] is None
-    assert r["lock_wait_share"] is None
-    assert r["wait_timeouts"] == 0
-    assert {name for name, _, _ in r["named_gaps"]} == {"none"}
-    assert [ns for _, ns, _ in r["named_gaps"]] == [
-        ns for _, ns in trace.reduce(str(SMALL)).gaps]
+    ones found by host state."""
+    r = trace.reduce(str(SMALL))
+    assert r.spans == []
+    assert spans.engine_ms_per_task(r.spans) is None
+    assert spans.task_handoff_ms(r.spans) is None
+    assert spans.lock_wait_share(r.spans) is None
+    assert spans.wait_timeouts(r.spans) == 0
+    assert {name for name, _, _ in r.span_gaps} == {"none"}
+    assert [ns for _, ns, _ in r.span_gaps] == [ns for _, ns in r.gaps]
 
 
 def test_hunt_keeps_counters_per_instance(tmp_path):
@@ -114,3 +132,37 @@ def test_hunt_keeps_counters_per_instance(tmp_path):
                    for n, total, top in rec["gc"].values())
         (dur, at, task), *_ = rec["longest_calls"]
         assert 0.0 < dur < rec["wall_s"] and at >= 0.0 and "[" in task
+
+
+def test_traced_run_keeps_counters_per_instance(monkeypatch):
+    """A ``--trace 1`` run of the tiny cell on the CPU: every instance of
+    the window reports its executor and payload counters, the readers'
+    ``Context`` carries the traced instance's, and the reduction is given
+    every program's HLO text.  The CPU's trace has no TPU plane, so the
+    recorded v5e trace stands in for it."""
+    from bench import run as bench_run
+    from bench.tests import tiny
+
+    texts, contexts = [], []
+    small = trace.reduce(str(SMALL))
+    monkeypatch.setattr(trace, "reduce",
+                        lambda path, hlo_texts=(): (texts.append(hlo_texts),
+                                                    small)[1])
+    monkeypatch.setattr(bench_run, "_reader",
+                        lambda name: lambda ctx: contexts.append(ctx))
+    cell = tiny.cell("ddmd.qwen2-0.5b")
+    res = bench_run.run("ddmd.qwen2-0.5b", 3, 0.5, True, require_chip=False,
+                        cell=cell, log=lambda *a: None)
+    steps = cell["traffic"]["shapes"]["decode_steps"]
+    counters = res["window"]["counters"]
+    assert len(counters) == len(res["window"]["instance_walls"]) >= 1
+    for c in counters:
+        assert c["perf"]["starts"] == 48 and c["perf"]["passes"] > 0
+        assert c["payload"]["lock_acquires"] == 6 * 3 * steps + 3 + 18
+    assert len(contexts) == len(cell["per_layer"])
+    assert all(ctx.counters == counters[0] for ctx in contexts)
+    (given,) = texts
+    assert sorted(t.split()[1].rstrip(",") for t in given) == [
+        "jit_decode", "jit_prefill", "jit_serving_params", "jit_train_step"]
+    assert [name for name, _ in res["breakdown"]["span_gaps"]] == [
+        name for name, _, _ in small.span_gaps]

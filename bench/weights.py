@@ -1,12 +1,10 @@
 """Weights and inputs from the seed: the yardstick's own generators.
 
-The parameter tree follows the layout the program's decoder-only
-transformer reads (stacked layers under ``blocks``); every size comes
-from the configuration file under ``bench/configs``.  Weights are made
-on the device in one jitted call, in float32, the type the program's
-train state holds them in.  Norm scales and QKV biases are drawn away
-from the program's own initialisation (ones, zeros) so that a program
-that ignored them would read as wrong.
+The parameter tree is the ``layout`` of the configuration's architecture
+module (``bench/arch``), which follows the tree the program reads; every
+size comes from the configuration file under ``bench/configs``.  Weights
+are made on the device in one jitted call, in float32, the type the
+program's train state holds them in.
 
 Prompts and training batches are the ones ``Model.make_batch`` draws
 from a key: uniform token ids from ``jax.random.randint`` on the first
@@ -21,41 +19,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from bench import arch
+
 #: the largest value a task index may take: the program seeds its batches
 #: with ``PRNGKey(100 + i)``, which keeps only 32 bits
 INDEX_SPAN = 1 << 30
-
-
-def layout(cfg: dict) -> dict:
-    """``{path: (shape, kind, std)}`` for every parameter leaf, where
-    ``kind`` is ``normal`` (zero mean), ``scale`` (mean one) or ``bias``."""
-    d = cfg["hidden_size"]
-    f = cfg["intermediate_size"]
-    L = cfg["num_hidden_layers"]
-    hd = cfg["head_dim"]
-    q = cfg["num_attention_heads"] * hd
-    kv = cfg["num_key_value_heads"] * hd
-    v = cfg["vocab_size"]
-    out = {
-        ("embedding",): ((v, d), "normal", 0.02),
-        ("final_norm",): ((d,), "scale", 0.05),
-        ("blocks", "ln1"): ((L, d), "scale", 0.05),
-        ("blocks", "ln2"): ((L, d), "scale", 0.05),
-        ("blocks", "attn", "wq"): ((L, d, q), "normal", d ** -0.5),
-        ("blocks", "attn", "wk"): ((L, d, kv), "normal", d ** -0.5),
-        ("blocks", "attn", "wv"): ((L, d, kv), "normal", d ** -0.5),
-        ("blocks", "attn", "wo"): ((L, q, d), "normal", q ** -0.5),
-        ("blocks", "mlp", "gate"): ((L, d, f), "normal", d ** -0.5),
-        ("blocks", "mlp", "up"): ((L, d, f), "normal", d ** -0.5),
-        ("blocks", "mlp", "down"): ((L, f, d), "normal", f ** -0.5),
-    }
-    if not cfg["tie_word_embeddings"]:
-        out[("lm_head",)] = ((d, v), "normal", d ** -0.5)
-    if cfg.get("attention_bias"):
-        out[("blocks", "attn", "bq")] = ((L, q), "bias", 0.02)
-        out[("blocks", "attn", "bk")] = ((L, kv), "bias", 0.02)
-        out[("blocks", "attn", "bv")] = ((L, kv), "bias", 0.02)
-    return out
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -90,13 +58,14 @@ def _draw_jit(key, lay: tuple) -> dict:
 
 def make_params(cfg: dict, seed: int) -> dict:
     """The parameter tree for ``seed``, made on the default device."""
-    return _draw_jit(seed_key(seed), tuple(sorted(layout(cfg).items())))
+    lay = arch.of(cfg).layout(cfg)
+    return _draw_jit(seed_key(seed), tuple(sorted(lay.items())))
 
 
 def params_fn(cfg: dict):
     """``key -> params`` unjitted, for callers that fuse it into a larger
     jitted function (a fresh train state, a difference of parameters)."""
-    lay = layout(cfg)
+    lay = arch.of(cfg).layout(cfg)
     return lambda key: _draw(key, lay)
 
 
