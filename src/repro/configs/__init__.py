@@ -23,6 +23,7 @@ _MODULES = {
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
     "llama4-scout-17b-a16e": "llama4_scout_17b_a16e",
     "qwen2-vl-7b": "qwen2_vl_7b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 ARCH_IDS = tuple(_MODULES)

@@ -22,9 +22,11 @@ NEG_INF = -1e30
 
 
 def decode_attention_jnp(q, cache_k, cache_v, valid, *, pos=None,
-                         window=None, chunk=None, rolling=False):
+                         window=None, chunk=None, rolling=False, scale=None,
+                         dv=None):
     return ref.decode_reference(q, cache_k, cache_v, valid, pos=pos,
-                                window=window, chunk=chunk, rolling=rolling)
+                                window=window, chunk=chunk, rolling=rolling,
+                                scale=scale, dv=dv)
 
 
 def default_impl() -> str:
@@ -33,17 +35,23 @@ def default_impl() -> str:
 
 
 def decode_attention(q, cache_k, cache_v, valid, *, pos=None, window=None,
-                     chunk=None, rolling=False, impl="auto", interpret=None):
+                     chunk=None, rolling=False, scale=None, dv=None,
+                     impl="auto", interpret=None):
+    """``scale`` defaults to ``D ** -0.5``; with ``cache_v`` None the
+    values are the keys' first ``dv`` lanes (latent attention)."""
     if impl == "auto":
         impl = default_impl()
+    kw = dict(pos=pos, window=window, chunk=chunk, rolling=rolling)
+    if scale is not None:
+        kw["scale"] = scale
+    if cache_v is None:
+        kw["dv"] = dv
     if impl == "pallas":
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
-        return decode_attention_pallas(
-            q, cache_k, cache_v, valid, pos=pos, window=window, chunk=chunk,
-            rolling=rolling, interpret=interpret)
-    return decode_attention_jnp(q, cache_k, cache_v, valid, pos=pos,
-                                window=window, chunk=chunk, rolling=rolling)
+        return decode_attention_pallas(q, cache_k, cache_v, valid, **kw,
+                                       interpret=interpret)
+    return decode_attention_jnp(q, cache_k, cache_v, valid, **kw)
 
 
 # ---------------------------------------------------------------------------

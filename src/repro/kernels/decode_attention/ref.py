@@ -6,11 +6,15 @@ import jax.numpy as jnp
 
 
 def decode_reference(q, cache_k, cache_v, valid, *, pos=None, window=None,
-                     chunk=None, rolling=False, scale=None):
+                     chunk=None, rolling=False, scale=None, dv=None):
     """q: [B, H, D]; cache_k/v: [B, S, KVH, D]; valid: [B] (# live slots);
     pos: [B] absolute position of the current token (needed for window /
-    chunk masks on non-rolling caches).  Returns [B, H, D].
+    chunk masks on non-rolling caches).  Returns [B, H, D]; with
+    ``cache_v`` None the values are ``cache_k[..., :dv]`` and it returns
+    [B, H, dv].
     """
+    if cache_v is None:
+        cache_v = cache_k[..., :dv or cache_k.shape[-1]]
     b, h, d = q.shape
     _, s, kvh, _ = cache_k.shape
     group = h // kvh

@@ -22,7 +22,7 @@ decode step re-casts the whole float32 tree and writes the copies out.
 Each payload call is a ``ddmd:<kind>`` span (``repro.core.tracing``); in
 it, ``ddmd:lock`` spans the wait for the lock (not the dispatch under it)
 and ``ddmd:block`` the wait for the device.  ``counters`` sums those two
-waits.
+waits, and an expert model's train steps' assignments to each expert.
 
 ``run`` drives the workflow through ``RealExecutor`` with the same
 (cpus, gpus) accounting as the paper's middleware: sequential mode
@@ -38,6 +38,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import RealExecutor, RunConfig, deepdrivemd_dag
 from repro.core.executor import ExecResult
@@ -86,6 +87,9 @@ class PayloadCounters:
     #: serving copies made (one per train-state version that a rollout or
     #: prefill read)
     casts: int = 0
+    #: an expert model's train steps: assignments to each held expert,
+    #: summed over layers and steps
+    expert_tokens: list = dataclasses.field(default_factory=list)
 
 
 class DDMDPayloads:
@@ -224,7 +228,15 @@ class DDMDPayloads:
                 self.state, metrics = self.compiled["train"](self.state,
                                                              batch)
             self.losses.append(metrics["loss"])
-            return self._block(metrics["loss"])
+            out = self._block(metrics["loss"])
+            if "expert_tokens" in metrics:
+                # the step has run: reading its counts waits for nothing
+                counts = np.asarray(metrics["expert_tokens"]).tolist()
+                with self._count_lock:
+                    c = self.counters
+                    c.expert_tokens = [a + b for a, b in zip(
+                        c.expert_tokens or [0.0] * len(counts), counts)]
+            return out
 
     def inference(self, i: int):
         with span("ddmd:inference"):

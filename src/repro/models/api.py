@@ -6,6 +6,8 @@ talks to this interface only:
     m = build_model(cfg)
     m.specs()                         -> ParamSpec pytree
     m.loss(params, batch)             -> scalar (train objective)
+    m.loss_and_stats(params, batch)   -> (scalar, dict of step statistics:
+                                         an expert model's ``expert_tokens``)
     m.forward(params, batch)          -> (logits, aux)
     m.cache_specs(batch, s_max)       -> ParamSpec pytree (decode state)
     m.decode_step(params, cache, tokens, pos) -> (logits [B, V], cache)
@@ -39,12 +41,19 @@ class Model:
     _cache_specs: Callable | None
     _decode: Callable | None
     _serving: Callable | None = None
+    _loss_stats: Callable | None = None
 
     def specs(self):
         return self._specs(self.cfg)
 
     def loss(self, params, batch):
         return self._loss(params, batch, self.cfg)
+
+    def loss_and_stats(self, params, batch):
+        """(loss, stats); a family that keeps none gives ``{}``."""
+        if self._loss_stats is None:
+            return self.loss(params, batch), {}
+        return self._loss_stats(params, batch, self.cfg)
 
     def forward(self, params, batch, **kw):
         return self._forward(params, batch, self.cfg, **kw)
@@ -122,7 +131,8 @@ def build_model(cfg: ModelConfig) -> Model:
     if cfg.family in ("dense", "moe", "vlm"):
         return Model(cfg, tf_model.transformer_specs, tf_model.loss_fn,
                      tf_model.forward, tf_model.init_cache_specs,
-                     tf_model.decode_step, tf_model.serving_params)
+                     tf_model.decode_step, tf_model.serving_params,
+                     tf_model.loss_and_stats)
     if cfg.family == "ssm" and cfg.rwkv:
         return Model(cfg, rwkv_model.rwkv6_specs, rwkv_model.loss_fn,
                      rwkv_model.forward, rwkv_model.init_cache_specs,

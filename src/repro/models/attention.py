@@ -1,6 +1,13 @@
 """GQA attention for all transformer-family archs: full / sliding-window /
 chunked-local(+periodic-global) masks, QKV bias, per-head qk-norm, partial
-RoPE and M-RoPE; prefill and single-token decode against a KV cache."""
+RoPE and M-RoPE; prefill and single-token decode against a KV cache.
+
+Multi-head latent attention (deepseek-v2/v3, ``cfg.mla``): keys and values
+come from one normed latent per token plus one rotary key that all heads
+share.  Prefill and training expand the latent to per-head keys and
+values; decode absorbs the key expansion into the query and attends over
+a cache of [latent, rotary key] per token, whose first ``kv_lora_rank``
+lanes are also the values, expanded after attending."""
 
 from __future__ import annotations
 
@@ -18,7 +25,14 @@ from .layers import apply_rope, rms_norm
 from .params import spec
 
 
+#: eps of the latent's RMSNorm (``kv_a_layernorm``), which the published
+#: deepseek modeling code leaves at its norm's default
+LATENT_NORM_EPS = 1e-6
+
+
 def attention_specs(cfg: ModelConfig, layers: int):
+    if cfg.mla:
+        return mla_specs(cfg, layers)
     d, q, kv = cfg.d_model, cfg.q_dim, cfg.kv_dim
     L = (layers,)
     out = {
@@ -115,21 +129,57 @@ def _shardmapped_flash(q, k, v, mesh, batch_axes, heads_axis, **kw):
 def _shardmapped_decode(q, cache_k, cache_v, valid, pos, mesh, batch_axes,
                         **kw):
     """Decode attention per device under shard_map, batch over
-    ``batch_axes``: GSPMD cannot partition the Pallas decode kernel."""
-    def body(q, ck, cv, valid, pos):
+    ``batch_axes``: GSPMD cannot partition the Pallas decode kernel.
+    ``cache_v`` None: the values are the keys' lanes (latent attention)."""
+    caches = (cache_k,) if cache_v is None else (cache_k, cache_v)
+
+    def body(q, *rest):
+        *cs, valid, pos = rest
+        ck, cv = cs if len(cs) == 2 else (cs[0], None)
         return da.decode_attention(q, ck, cv, valid, pos=pos, **kw)
 
     spec = P(_entry(batch_axes))
-    return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 5,
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec,) * (3 + len(caches)),
                          out_specs=spec, check_vma=False)(
-        q, cache_k, cache_v, valid, pos)
+        q, *caches, valid, pos)
 
 
-def self_attention(p, x, cfg: ModelConfig, positions, *, causal=True,
-                   window=None, chunk=None, rope=True):
-    """Training / prefill attention.  x: [B, S, D]."""
-    q, k, v = _project_qkv(p, x, cfg, positions, rope=rope)
-    b, s = x.shape[:2]
+def mla_specs(cfg: ModelConfig, layers: int):
+    d, r, h = cfg.d_model, cfg.kv_lora_rank, cfg.num_heads
+    L = (layers,)
+    return {
+        "wq": spec(L + (d, cfg.q_dim), ("layers", "embed", "heads")),
+        "wkv_a": spec(L + (d, r + cfg.qk_rope_head_dim),
+                      ("layers", "embed", None)),
+        "kv_norm": spec(L + (r,), ("layers", None), init="ones"),
+        "wkv_b": spec(L + (r, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                      ("layers", None, "heads")),
+        "wo": spec(L + (h * cfg.v_head_dim, d), ("layers", "heads", "embed")),
+    }
+
+
+def _mla_project(p, x, cfg: ModelConfig, positions):
+    """Queries [B, S, H, nope + rope] with rotary on their rope part; the
+    normed latent [B, S, r]; the rotary key [B, S, 1, rope]."""
+    b, s, _ = x.shape
+    dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    rot = dict(theta=cfg.rope_theta)
+    q = (x @ gathered(p["wq"], "embed", "heads", dtype=x.dtype)).reshape(
+        b, s, cfg.num_heads, cfg.head_dim)
+    q = jnp.concatenate([q[..., :dn],
+                         apply_rope(q[..., dn:], positions, **rot)], -1)
+    kv = x @ p["wkv_a"].astype(x.dtype)
+    c = rms_norm(kv[..., :r], p["kv_norm"].astype(jnp.float32),
+                 LATENT_NORM_EPS)
+    k_rope = apply_rope(kv[..., None, r:], positions, **rot)
+    return q, c, k_rope
+
+
+def _flash(q, k, v, cfg: ModelConfig, **kw):
+    """Flash attention on [B, S, H, D] queries, on a mesh under shard_map
+    where GSPMD cannot partition the kernel or heads split over 'model'."""
+    b = q.shape[0]
     mesh = current_mesh()
     m = mesh.shape.get("model", 1) if mesh is not None else 1
     split_heads = (m > 1 and cfg.num_heads % m == 0
@@ -137,13 +187,41 @@ def self_attention(p, x, cfg: ModelConfig, positions, *, causal=True,
     if mesh is not None and (
             fa.default_impl() == "pallas"
             or (current_flags().get("headparallel_attn") and split_heads)):
-        out = _shardmapped_flash(q, k, v, mesh, _batch_axes(mesh, "batch", b),
-                                 "model" if split_heads else None,
-                                 causal=causal, window=window, chunk=chunk)
-    else:
-        q = shard_act(q, "batch", "seq", "act_heads", None)
-        out = fa.flash_attention(q, k, v, causal=causal, window=window,
-                                 chunk=chunk)
+        return _shardmapped_flash(q, k, v, mesh,
+                                  _batch_axes(mesh, "batch", b),
+                                  "model" if split_heads else None, **kw)
+    q = shard_act(q, "batch", "seq", "act_heads", None)
+    return fa.flash_attention(q, k, v, **kw)
+
+
+def mla_self_attention(p, x, cfg: ModelConfig, positions):
+    """Training / prefill latent attention, expanded: per-head keys
+    [nope from the latent, the shared rotary key] and values from the
+    latent; scale (nope + rope) ** -0.5.  The values are zero-padded to
+    the key width for the flash kernel (one width for q, k and v) and
+    sliced after.  x: [B, S, D]."""
+    b, s, _ = x.shape
+    h, dn, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    q, c, k_rope = _mla_project(p, x, cfg, positions)
+    kv = (c @ gathered(p["wkv_b"], None, "heads", dtype=x.dtype)).reshape(
+        b, s, h, dn + dv)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, h, k_rope.shape[-1]))],
+        -1)
+    v = jnp.pad(kv[..., dn:], ((0, 0),) * 3 + ((0, cfg.head_dim - dv),))
+    out = _flash(q, k, v, cfg, causal=True)[..., :dv]
+    return out.reshape(b, s, h * dv) @ gathered(p["wo"], "heads", "embed",
+                                                dtype=x.dtype)
+
+
+def self_attention(p, x, cfg: ModelConfig, positions, *, causal=True,
+                   window=None, chunk=None, rope=True):
+    """Training / prefill attention.  x: [B, S, D]."""
+    if cfg.mla:
+        return mla_self_attention(p, x, cfg, positions)
+    q, k, v = _project_qkv(p, x, cfg, positions, rope=rope)
+    b, s = x.shape[:2]
+    out = _flash(q, k, v, cfg, causal=causal, window=window, chunk=chunk)
     out = out.reshape(b, s, cfg.q_dim)
     return out @ gathered(p["wo"], "heads", "embed", dtype=x.dtype)
 
@@ -208,13 +286,8 @@ def decode_attention(p, x, cfg: ModelConfig, cache_k, cache_v, pos, *,
         slot = pos % s_max
     else:
         slot = pos
-    idx = slot[:, None]
-    cache_k = jax.vmap(
-        lambda c, kk, i: jax.lax.dynamic_update_slice(c, kk, (i, 0, 0))
-    )(cache_k, k.astype(cache_k.dtype), slot)
-    cache_v = jax.vmap(
-        lambda c, vv, i: jax.lax.dynamic_update_slice(c, vv, (i, 0, 0))
-    )(cache_v, v.astype(cache_v.dtype), slot)
+    cache_k = _write_slot(cache_k, k, slot)
+    cache_v = _write_slot(cache_v, v, slot)
     valid = jnp.minimum(pos + 1, s_max)
     kw = dict(window=window, chunk=chunk,
               rolling=window is not None and s_max <= window)
@@ -229,8 +302,52 @@ def decode_attention(p, x, cfg: ModelConfig, cache_k, cache_v, pos, *,
     return out @ p["wo"].astype(x.dtype), cache_k, cache_v
 
 
+def _write_slot(cache, entry, slot):
+    """``cache`` [B, S, ...] with ``entry`` [B, 1, ...] written at each
+    row's ``slot``."""
+    zeros = (0,) * (cache.ndim - 2)
+    return jax.vmap(
+        lambda c, e, i: jax.lax.dynamic_update_slice(c, e, (i,) + zeros)
+    )(cache, entry.astype(cache.dtype), slot)
+
+
+def mla_decode_attention(p, x, cfg: ModelConfig, cache, pos):
+    """Single-token latent attention, absorbed.  x: [B, 1, D]; cache:
+    [B, S_max, kv_lora_rank + rope] (normed latent, rotary key); pos: [B].
+    The key expansion ``wkv_b[:, :, :nope]`` folds into the query, which
+    attends over the one shared cache "head"; the attended latent (the
+    cache's first ``kv_lora_rank`` lanes) is expanded by the value half
+    after.  Returns (out [B, 1, D], new cache)."""
+    b = x.shape[0]
+    h, dn, dv, r = (cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim,
+                    cfg.kv_lora_rank)
+    q, c, k_rope = _mla_project(p, x, cfg, pos[:, None])
+    cache = _write_slot(cache, jnp.concatenate([c, k_rope[:, :, 0]], -1),
+                        pos)
+    wkv_b = p["wkv_b"].astype(x.dtype).reshape(r, h, dn + dv)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q[:, 0, :, :dn], wkv_b[..., :dn])
+    qf = jnp.concatenate([q_lat, q[:, 0, :, dn:]], -1)      # [B, H, r+rope]
+    valid = jnp.minimum(pos + 1, cache.shape[1])
+    kw = dict(scale=cfg.head_dim ** -0.5, dv=r)
+    mesh = current_mesh()
+    if mesh is not None and da.default_impl() == "pallas":
+        o = _shardmapped_decode(qf, cache[:, :, None], None, valid, pos,
+                                mesh, _batch_axes(mesh, "cache_batch", b),
+                                **kw)
+    else:
+        o = da.decode_attention(qf, cache[:, :, None], None, valid, pos=pos,
+                                **kw)
+    out = jnp.einsum("bhr,rhv->bhv", o, wkv_b[..., dn:])
+    return out.reshape(b, 1, h * dv) @ p["wo"].astype(x.dtype), cache
+
+
 def cache_shape(cfg: ModelConfig, batch: int, s_max: int):
-    """KV cache ShapeDtypeStruct axes for one layer stack."""
+    """KV cache ShapeDtypeStruct axes for one layer stack (latent
+    attention: one [latent, rotary key] entry per token, no V)."""
+    if cfg.mla:
+        return ((cfg.num_layers, batch, s_max,
+                 cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+                ("layers", "cache_batch", "cache_seq", None))
     if cfg.sliding_window is not None:
         s_max = min(s_max, cfg.sliding_window)
     shape = (cfg.num_layers, batch, s_max, cfg.num_kv_heads, cfg.head_dim)

@@ -1,7 +1,6 @@
 """Mixture-of-Experts layer with expert parallelism.
 
-Three dispatch strategies sharing the same capacity-based scatter/combine
-helpers:
+Three dispatch strategies:
 
 - ``moe_ep_a2a``   (train / prefill): shard_map over the full mesh; tokens
   are sharded over ('pod','data') x batch and 'model' x sequence, experts
@@ -15,12 +14,23 @@ helpers:
   its local experts, and the combine is a psum.  No all_to_all on the
   latency-critical decode path; traffic is 2 x activation bytes.
 
-- ``moe_local``   (no mesh / smoke tests): the same scatter-dispatch on a
-  single device, no collectives.
+- ``moe_local``   (one device): dropless.  The assignments to the experts
+  this layer holds are sorted by expert and run as one grouped matmul
+  (``jax.lax.ragged_dot``); none is dropped.
 
-Routing is classic top-k with optional renormalised weights (qwen3) and a
-load-balance auxiliary loss (Shazeer-style f*P); overflowed tokens beyond
-the capacity factor are dropped (counted into the aux metrics).
+Only the two mesh paths have a capacity: their all-to-all and psum
+buffers need a static size, and assignments past it are dropped.
+
+Routing: softmax top-k with optional renormalised weights (qwen3) and a
+load-balance auxiliary loss (Shazeer-style f*P); or deepseek-v3's
+``noaux_tc``: sigmoid scores, top-k of score plus a per-expert selection
+bias (``n_group`` 1), weights the scores at the selection, normalised
+and scaled by ``routed_scaling_factor``, and no auxiliary loss.  The bias
+is held, not trained: no gradient reaches it.
+
+A layer may hold a share of the experts (``cfg.held_experts`` from
+``cfg.first_held_expert``, expert parallelism's share of one chip): it
+routes over all ``num_experts`` and adds only its own experts' part.
 """
 
 from __future__ import annotations
@@ -38,10 +48,11 @@ from .params import spec
 
 
 def moe_specs(cfg: ModelConfig, layers: int):
-    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.num_experts
+    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.n_held
     L = (layers,)
     out = {
-        "router": spec(L + (d, e), ("layers", "embed", None), scale=0.02),
+        "router": spec(L + (d, cfg.num_experts), ("layers", "embed", None),
+                       scale=0.02),
         "w_gate": spec(L + (e, d, f), ("layers", "experts", "embed",
                                        "expert_ffn")),
         "w_up": spec(L + (e, d, f), ("layers", "experts", "embed",
@@ -50,10 +61,11 @@ def moe_specs(cfg: ModelConfig, layers: int):
                                        "embed")),
     }
     if cfg.shared_expert:
+        fs = cfg.shared_width
         out |= {
-            "s_gate": spec(L + (d, cfg.d_ff), ("layers", "embed", "ffn")),
-            "s_up": spec(L + (d, cfg.d_ff), ("layers", "embed", "ffn")),
-            "s_down": spec(L + (cfg.d_ff, d), ("layers", "ffn", "embed")),
+            "s_gate": spec(L + (d, fs), ("layers", "embed", "ffn")),
+            "s_up": spec(L + (d, fs), ("layers", "embed", "ffn")),
+            "s_down": spec(L + (fs, d), ("layers", "ffn", "embed")),
         }
     return out
 
@@ -68,18 +80,52 @@ class MoEOptions:
 # routing + scatter helpers (shared by all strategies)
 # ---------------------------------------------------------------------------
 
-def _route(router_w, x, cfg: ModelConfig):
-    """x: [T, D] -> (weights [T, k], experts [T, k], aux_loss scalar)."""
-    logits = (x.astype(jnp.float32) @ router_w.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                    # [T, E]
-    w, e = jax.lax.top_k(probs, cfg.experts_per_token)         # [T, k]
+def _route(router_w, x, cfg: ModelConfig, bias=None):
+    """x: [T, D] -> (weights [T, k], experts [T, k], aux_loss scalar).
+    ``bias`` [E]: the selection bias of ``noaux_tc`` routing."""
+    # float32 at HIGHEST: the TPU's default pass rounds both operands to
+    # bfloat16, which moves the selection at near ties
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if cfg.noaux_tc:
+        probs = jax.nn.sigmoid(logits)                         # [T, E]
+        _, e = jax.lax.top_k(probs + jax.lax.stop_gradient(bias),
+                             cfg.experts_per_token)
+        w = jnp.take_along_axis(probs, e, axis=1)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        w, e = jax.lax.top_k(probs, cfg.experts_per_token)    # [T, k]
     if cfg.norm_topk:
         w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    w = w * cfg.routed_scaling_factor
+    if cfg.noaux_tc:
+        return w, e, jnp.zeros((), jnp.float32)
     # load-balance aux: E * sum_e mean(onehot_e) * mean(prob_e)
     ids = jax.nn.one_hot(e[:, 0], cfg.num_experts, dtype=jnp.float32)
     aux = cfg.num_experts * jnp.mean(
         ids.mean(0) * probs.mean(0)) * cfg.num_experts
     return w, e, aux
+
+
+def _held_ids(e, cfg: ModelConfig):
+    """Each assignment's expert among those held, ``n_held`` where the
+    expert is not held."""
+    le = e - cfg.first_held_expert
+    return jnp.where((le >= 0) & (le < cfg.n_held), le, cfg.n_held)
+
+
+def _held_counts(le, cfg: ModelConfig):
+    """Assignments to each held expert, from ``_held_ids``."""
+    return jnp.bincount(le.reshape(-1), length=cfg.n_held + 1)[:cfg.n_held]
+
+
+def _mesh_counts(e, cfg: ModelConfig, axes):
+    """Assignments to each expert [num_experts] (float32) of a mesh body's
+    routing ``e``, summed over the mesh ``axes`` its tokens are split
+    over."""
+    c = jnp.bincount(e.reshape(-1), length=cfg.num_experts)
+    c = c.astype(jnp.float32)
+    return jax.lax.psum(c, axes) if axes else c
 
 
 def _positions_in_expert(flat_e, num_experts: int):
@@ -122,31 +168,50 @@ def _capacity(tokens: int, num_experts: int, k: int, factor: float) -> int:
 # strategies
 # ---------------------------------------------------------------------------
 
-def _moe_tokens(p, xt, cfg: ModelConfig, opts: MoEOptions):
-    """Single-device dispatch on a flat token batch xt: [T, D]."""
+def _moe_tokens(p, xt, cfg: ModelConfig):
+    """Dropless single-device dispatch on a flat token batch xt: [T, D]:
+    the held experts' assignments sorted by expert, one grouped matmul
+    per weight, the rest (not held here) sorted last and left out."""
     t, dd = xt.shape
-    w, e, aux = _route(p["router"], xt, cfg)
     k = cfg.experts_per_token
-    cap = _capacity(t, cfg.num_experts, k, opts.capacity_factor)
-    flat_e = e.reshape(t * k)
-    pos = _positions_in_expert(flat_e, cfg.num_experts)
-    x_rep = jnp.repeat(xt, k, axis=0)                          # [T*k, D]
-    buf, keep = _dispatch(x_rep, flat_e, pos, cap, cfg.num_experts)
-    out_buf = _expert_ffn(p, buf)
-    y = _collect(out_buf, flat_e, pos, cap, keep)              # [T*k, D]
-    y = (y.reshape(t, k, dd) * w[..., None].astype(y.dtype)).sum(axis=1)
-    return y, aux
+    w, e, aux = _route(p["router"], xt, cfg, p.get("bias"))
+    le = _held_ids(e, cfg).reshape(t * k)
+    order = jnp.argsort(le, stable=True)
+    sizes = _held_counts(le, cfg)
+    # the grouped matmul leaves the rows past its groups unspecified (a
+    # TPU need not write them), in its output and in its input's
+    # cotangent: each ``where`` zeroes them both ways
+    held = (jnp.arange(t * k) < sizes.sum())[:, None]
+    xs = jnp.where(held, xt[order // k], 0)                    # [T*k, D]
+
+    def gmm(a, wt):
+        return jnp.where(held, jax.lax.ragged_dot(a, wt.astype(a.dtype),
+                                                  sizes), 0)
+
+    h = jax.nn.silu(gmm(xs, p["w_gate"])) * gmm(xs, p["w_up"])
+    ws = w.reshape(t * k)[order]
+    ys = gmm(h, p["w_down"]) * ws[:, None].astype(xs.dtype)
+    y = ys[jnp.argsort(order)].reshape(t, k, dd).sum(axis=1)
+    return y, aux, sizes.astype(jnp.float32)
 
 
 def moe_local(p, x, cfg: ModelConfig, opts: MoEOptions = MoEOptions()):
+    """One device, dropless: ``opts.capacity_factor`` does not apply.
+    Returns (y, aux, assignments to each held expert)."""
     b, s, dd = x.shape
-    y, aux = _moe_tokens(p, x.reshape(b * s, dd), cfg, opts)
-    return y.reshape(b, s, dd), aux
+    y, aux, counts = _moe_tokens(p, x.reshape(b * s, dd), cfg)
+    return y.reshape(b, s, dd), aux, counts
 
 
 def _dev_groups(mesh):
     """(model-axis size, experts per model rank)."""
     return mesh.shape["model"]
+
+
+def _bias(p, cfg: ModelConfig):
+    """The selection bias, zeros where routing has none (a mesh path's
+    shard_map takes an array either way)."""
+    return p.get("bias", jnp.zeros((cfg.num_experts,), jnp.float32))
 
 
 def moe_ep_a2a(p, x, cfg: ModelConfig, opts: MoEOptions = MoEOptions()):
@@ -161,11 +226,11 @@ def moe_ep_a2a(p, x, cfg: ModelConfig, opts: MoEOptions = MoEOptions()):
     k = cfg.experts_per_token
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
-    def body(router_w, w_gate, w_up, w_down, xs):
+    def body(router_w, bias, w_gate, w_up, w_down, xs):
         bl, sl, dd = xs.shape
         t = bl * sl
         xt = xs.reshape(t, dd)
-        w, e, aux = _route(router_w, xt, cfg)
+        w, e, aux = _route(router_w, xt, cfg, bias)
         aux = jax.lax.pmean(aux, ("model",) + batch_axes)
         cap = _capacity(t, cfg.num_experts, k, opts.capacity_factor)
         flat_e = e.reshape(t * k)
@@ -188,7 +253,7 @@ def moe_ep_a2a(p, x, cfg: ModelConfig, opts: MoEOptions = MoEOptions()):
         y = _collect(cb.reshape(cfg.num_experts, cap, dd), flat_e, pos,
                      cap, keep)
         y = (y.reshape(t, k, dd) * w[..., None].astype(y.dtype)).sum(axis=1)
-        return y.reshape(bl, sl, dd), aux
+        return y.reshape(bl, sl, dd), aux, _mesh_counts(e, cfg, split)
 
     rules = current_rules()
     baxes = tuple(a for a in rules.mesh_axes_for("batch", mesh)
@@ -196,14 +261,16 @@ def moe_ep_a2a(p, x, cfg: ModelConfig, opts: MoEOptions = MoEOptions()):
     # tokens are additionally split over 'model' along sequence unless the
     # batch dim already covers the model axis (full-DP variants)
     seq_entry = "model" if "model" not in baxes else None
+    split = baxes + ((seq_entry,) if seq_entry else ())
     xspec = P(baxes if len(baxes) > 1 else (baxes[0] if baxes else None),
               seq_entry, None)
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(None, None), P("model"), P("model"), P("model"), xspec),
-        out_specs=(xspec, P()),
+        in_specs=(P(None, None), P(None), P("model"), P("model"), P("model"),
+                  xspec),
+        out_specs=(xspec, P(), P()),
         check_vma=False,
-    )(p["router"], p["w_gate"], p["w_up"], p["w_down"], x)
+    )(p["router"], _bias(p, cfg), p["w_gate"], p["w_up"], p["w_down"], x)
 
 
 def moe_ep_psum(p, x, cfg: ModelConfig, opts: MoEOptions = MoEOptions()):
@@ -215,11 +282,11 @@ def moe_ep_psum(p, x, cfg: ModelConfig, opts: MoEOptions = MoEOptions()):
     k = cfg.experts_per_token
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
-    def body(router_w, w_gate, w_up, w_down, xs):
+    def body(router_w, bias, w_gate, w_up, w_down, xs):
         bl, sl, dd = xs.shape
         t = bl * sl
         xt = xs.reshape(t, dd)
-        w, e, aux = _route(router_w, xt, cfg)
+        w, e, aux = _route(router_w, xt, cfg, bias)
         aux = jax.lax.pmean(aux, ("model",) + batch_axes)
         my = jax.lax.axis_index("model")
         local = (e // e_loc) == my                              # [T, k]
@@ -234,23 +301,26 @@ def moe_ep_psum(p, x, cfg: ModelConfig, opts: MoEOptions = MoEOptions()):
         y = _collect(ob, flat_e, pos, cap, keep & (flat_e < e_loc))
         y = (y.reshape(t, k, dd) * w[..., None].astype(y.dtype)).sum(axis=1)
         y = jax.lax.psum(y, "model")
-        return y.reshape(bl, sl, dd), aux
+        return y.reshape(bl, sl, dd), aux, _mesh_counts(e, cfg, split)
 
     rules = current_rules()
-    xspec = P(rules.mesh_axes_for("batch", mesh) or None, None, None)
+    split = rules.mesh_axes_for("batch", mesh)
+    xspec = P(split or None, None, None)
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(None, None), P("model"), P("model"), P("model"), xspec),
-        out_specs=(xspec, P()),
+        in_specs=(P(None, None), P(None), P("model"), P("model"), P("model"),
+                  xspec),
+        out_specs=(xspec, P(), P()),
         check_vma=False,
-    )(p["router"], p["w_gate"], p["w_up"], p["w_down"], x)
+    )(p["router"], _bias(p, cfg), p["w_gate"], p["w_up"], p["w_down"], x)
 
 
 def moe_block(p, x, cfg: ModelConfig, *, decode: bool = False,
               opts: MoEOptions = MoEOptions()):
     """Dispatching MoE entry point; adds the shared expert if configured.
 
-    Returns (y [B,S,D], aux_loss scalar).
+    Returns (y [B,S,D], aux_loss scalar, assignments to each held expert
+    [n_held]).
 
     Perf flag ``moe_gather_bf16`` (§Perf hillclimb): expert weight stacks
     are cast to bf16 BEFORE the shard_map boundary, so the ZeRO-style
@@ -268,15 +338,16 @@ def moe_block(p, x, cfg: ModelConfig, *, decode: bool = False,
               and mesh.shape["model"] > 1
               and cfg.num_experts % mesh.shape["model"] == 0)
     if not use_ep:
-        y, aux = moe_local(p, x, cfg, opts)
-    elif decode or s % mesh.shape["model"] != 0:
-        y, aux = moe_ep_psum(p, x, cfg, opts)
+        y, aux, counts = moe_local(p, x, cfg, opts)
     else:
-        y, aux = moe_ep_a2a(p, x, cfg, opts)
+        assert cfg.n_held == cfg.num_experts, "a mesh holds every expert"
+        ep = (moe_ep_psum if decode or s % mesh.shape["model"] != 0
+              else moe_ep_a2a)
+        y, aux, counts = ep(p, x, cfg, opts)
     if cfg.shared_expert:
         from repro.runtime.sharding import gathered
         h = (jax.nn.silu(x @ gathered(p["s_gate"], "embed", "ffn",
                                       dtype=x.dtype))
              * (x @ gathered(p["s_up"], "embed", "ffn", dtype=x.dtype)))
         y = y + h @ gathered(p["s_down"], "ffn", "embed", dtype=x.dtype)
-    return y, aux * opts.aux_weight
+    return y, aux * opts.aux_weight, counts

@@ -28,18 +28,25 @@ class GradAccumulator:
         return jax.tree.map(re, batch)
 
     def grads(self, loss_fn, params, batch):
-        """Mean loss and mean grads over microbatches (scanned)."""
+        """Mean loss, mean grads and summed statistics over microbatches
+        (scanned); ``loss_fn`` returns (loss, stats)."""
         n = self.num_microbatches
+        vg = jax.value_and_grad(loss_fn, has_aux=True)
         if n <= 1:
-            return jax.value_and_grad(loss_fn)(params, batch)
+            (loss, stats), g = vg(params, batch)
+            return loss, g, stats
         micro = self.split(batch)
 
         def body(carry, mb):
-            loss_acc, g_acc = carry
-            loss, g = jax.value_and_grad(loss_fn)(params, mb)
+            loss_acc, g_acc, s_acc = carry
+            (loss, stats), g = vg(params, mb)
             g_acc = jax.tree.map(lambda a, b: a + b.astype(a.dtype), g_acc, g)
-            return (loss_acc + loss, g_acc), None
+            s_acc = jax.tree.map(jnp.add, s_acc, stats)
+            return (loss_acc + loss, g_acc, s_acc), None
 
         g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        (loss, g), _ = jax.lax.scan(body, (jnp.zeros(()), g0), micro)
-        return loss / n, jax.tree.map(lambda x: x / n, g)
+        s0 = jax.tree.map(jnp.zeros_like, jax.eval_shape(
+            loss_fn, params, jax.tree.map(lambda x: x[0], micro))[1])
+        (loss, g, stats), _ = jax.lax.scan(
+            body, (jnp.zeros(()), g0, s0), micro)
+        return loss / n, jax.tree.map(lambda x: x / n, g), stats

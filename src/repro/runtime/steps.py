@@ -1,7 +1,9 @@
 """Step builders: jit-compiled, mesh-sharded train / prefill / decode steps.
 
 ``build_train_step`` returns (step_fn, state_shardings, input_shardings)
-where step_fn: (TrainState, batch) -> (TrainState, metrics).  All sharding
+where step_fn: (TrainState, batch) -> (TrainState, metrics); the metrics
+are the loss, the gradient's norm, the learning rate and the model's step
+statistics (an expert model's ``expert_tokens``).  All sharding
 comes from the logical-axis rules (runtime/sharding.py); the same builder
 serves the real trainer, the smoke tests (mesh=None) and the dry-run
 (ShapeDtypeStructs via .lower()).
@@ -49,7 +51,7 @@ def _remat_loss(model: Model):
     body is the natural remat unit, so `jax.checkpoint` with a
     dots-saveable policy keeps matmul outputs and recomputes the rest."""
     policy = jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
-    return jax.checkpoint(model.loss, policy=policy)
+    return jax.checkpoint(model.loss_and_stats, policy=policy)
 
 
 def make_train_state(model: Model, key=None):
@@ -121,18 +123,18 @@ def build_train_step(model: Model, mesh=None,
         opts.schedule, peak_lr=opts.peak_lr, warmup=opts.warmup,
         total=opts.total_steps)
     accum = GradAccumulator(opts.microbatches)
-    loss_fn = _remat_loss(model) if opts.remat else model.loss
+    loss_fn = _remat_loss(model) if opts.remat else model.loss_and_stats
 
     def train_step(state: TrainState, batch):
         with use_sharding(mesh, rules, flags):
-            loss, grads = accum.grads(loss_fn, state.params, batch)
+            loss, grads, stats = accum.grads(loss_fn, state.params, batch)
             grads, gnorm = clip_by_global_norm(grads, opts.max_grad_norm)
             lr = sched(state.step)
             params, opt = adamw_update(
                 grads, state.opt, state.params, lr=lr,
                 weight_decay=opts.weight_decay)
         new = TrainState(step=state.step + 1, params=params, opt=opt)
-        return new, {"loss": loss, "grad_norm": gnorm, "lr": lr}
+        return new, {"loss": loss, "grad_norm": gnorm, "lr": lr, **stats}
 
     if mesh is None:
         # the state is donated, as on the mesh: the step updates it in
@@ -144,8 +146,7 @@ def build_train_step(model: Model, mesh=None,
     jitted = jax.jit(
         train_step,
         in_shardings=(shardings, None),      # batch sharding via data layer
-        out_shardings=(shardings,
-                       {"loss": rep, "grad_norm": rep, "lr": rep}),
+        out_shardings=(shardings, rep),
         donate_argnums=(0,),
     )
     return jitted, shardings

@@ -82,6 +82,23 @@ def _case(name):
         fn = decode_attention_pallas
         return fn, [((b, h, d), BF16), ((b, s, kvh, d), BF16),
                     ((b, s, kvh, d), BF16), ((b,), jnp.int32)]
+    if name == "decode_mla":
+        # moonlight's absorbed decode: 16 heads over one latent + rotary
+        # cache head, values its first 512 lanes
+        cfg = get_config("moonlight-16b-a3b")
+        b, s, w = 8, 2048, cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        def fn(q, k, valid):
+            return decode_attention_pallas(q, k, None, valid,
+                                           scale=cfg.head_dim ** -0.5,
+                                           dv=cfg.kv_lora_rank)
+        return fn, [((b, cfg.num_heads, w), BF16), ((b, s, 1, w), BF16),
+                    ((b,), jnp.int32)]
+    if name == "flash_mla":
+        # moonlight's expanded prefill: q and k of 128 + 64, v padded to it
+        cfg = get_config("moonlight-16b-a3b")
+        act = ((2, 1024, cfg.num_heads, cfg.head_dim), BF16)
+        return functools.partial(flash_attention_pallas, causal=True), [
+            act, act, act]
     if name == "rwkv6":
         cfg = get_config("rwkv6-1.6b")
         hh, t = cfg.d_model // HEAD_K, 1024
@@ -98,7 +115,7 @@ def _case(name):
 
 @pytest.mark.parametrize("name", [
     "flash_causal", "flash_window", "flash_chunk", "flash_s100",
-    "flash_grad", "decode", "rwkv6", "ssd"])
+    "flash_grad", "decode", "rwkv6", "ssd", "decode_mla", "flash_mla"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _case(name)
     shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)

@@ -95,22 +95,28 @@ def test_moe_ep_matches_local():
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
                               jnp.float32)
         opts = MoEOptions(capacity_factor=16.0)
-        y_ref, aux_ref = moe_local(p, x, cfg, opts)
+        y_ref, aux_ref, n_ref = moe_local(p, x, cfg, opts)
 
         mesh = Mesh(_np.asarray(jax.devices()).reshape(2, 4),
                     ("data", "model"))
         with use_sharding(mesh, ShardingRules()):
-            y_a2a, aux_a2a = jax.jit(
+            y_a2a, aux_a2a, n_a2a = jax.jit(
                 lambda p, x: moe_ep_a2a(p, x, cfg, opts))(p, x)
-            y_psum, aux_psum = jax.jit(
+            y_psum, aux_psum, n_psum = jax.jit(
                 lambda p, x: moe_ep_psum(p, x, cfg, opts))(p, x)
         print(json.dumps({
             "d_a2a": float(jnp.abs(y_a2a - y_ref).max()),
             "d_psum": float(jnp.abs(y_psum - y_ref).max()),
-            "aux_ref": float(aux_ref), "aux_a2a": float(aux_a2a)}))
+            "aux_ref": float(aux_ref), "aux_a2a": float(aux_a2a),
+            "counts": [_np.asarray(n).tolist()
+                       for n in (n_ref, n_a2a, n_psum)]}))
     """)
     assert res["d_a2a"] < 2e-3, res
     assert res["d_psum"] < 2e-3, res
+    # each mesh path counts its own routing's assignments, summed over the
+    # devices its tokens are split over: the local path's counts
+    n_ref, n_a2a, n_psum = res["counts"]
+    assert n_a2a == n_ref and n_psum == n_ref, res
 
 
 @pytest.mark.slow

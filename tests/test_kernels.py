@@ -89,6 +89,48 @@ def test_decode_attention(b, smax, h, kvh, d, dtype):
                                np.asarray(want, np.float32), **_tol(dtype))
 
 
+@pytest.mark.parametrize("impl", ["pallas", "jnp"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_latent(impl, dtype):
+    """Latent attention's absorbed decode: an explicit scale, one cache
+    "head" of latent + rotary lanes, and values that are its first ``dv``
+    lanes, against the reference given those values as a V cache."""
+    b, smax, h, dk, dv = 3, 64, 4, 96, 64
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    q = _rand(ks[0], (b, h, dk), dtype)
+    ck = _rand(ks[1], (b, smax, 1, dk), dtype)
+    valid = jnp.asarray([5, smax, 33])
+    scale = 48 ** -0.5
+    want = da_ref.decode_reference(q, ck, ck[..., :dv], valid, scale=scale)
+    got = da.decode_attention(q, ck, None, valid, scale=scale, dv=dv,
+                              impl=impl, interpret=True)
+    assert got.shape == (b, h, dv)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **_tol(dtype))
+
+
+def test_decode_attention_qwen2_call_unchanged():
+    """The kernel at qwen2's call (14 query heads over 2 KV heads of 64, no
+    scale given, a V cache) gives the result it gave before it learned the
+    latent form, bit for bit (interpret mode; the digest was recorded from
+    the kernel as it was)."""
+    import hashlib
+
+    from repro.kernels.decode_attention.kernel import decode_attention_pallas
+
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    b, s, h, kvh, d = 8, 256, 14, 2, 64
+    q = jax.random.normal(ks[0], (b, h, d)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, s, kvh, d)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, s, kvh, d)).astype(jnp.bfloat16)
+    valid = jnp.arange(1, b + 1, dtype=jnp.int32) * 3
+    out = decode_attention_pallas(q, k, v, valid, interpret=True)
+    digest = hashlib.sha256(
+        np.asarray(out.astype(jnp.float32)).tobytes()).hexdigest()
+    assert digest == ("ba477549849f0d8b1c8305feee3ad4c3"
+                      "c973def352a98e3a76178a71dbc26f30")
+
+
 # ---------------------------------------------------------------------------
 # rwkv6
 # ---------------------------------------------------------------------------

@@ -17,9 +17,9 @@ ART = os.path.join(os.path.dirname(__file__), "..", "experiments", "dryrun")
 
 def test_cell_matrix_is_complete():
     cells = all_cells()
-    assert len(cells) == 40
+    assert len(cells) == 44
     skips = [c for c in cells if c[2] == "skipped_full_attention"]
-    assert len(skips) == 7
+    assert len(skips) == 8
     # the skips are exactly long_500k on the pure full-attention archs
     assert all(s[1] == "long_500k" for s in skips)
     runs = {(a, s) for a, s, st in cells if st == "run"}
@@ -59,7 +59,7 @@ def test_dryrun_artifacts_schema():
     files = [f for f in files if "__hc" not in f]
     if not files:
         pytest.skip("dry-run artifacts not generated")
-    assert len(files) == 40
+    assert len(files) == len(all_cells())
     for f in files:
         with open(f) as fh:
             rec = json.load(fh)

@@ -13,11 +13,14 @@ from repro.models.params import init_params
 from repro.runtime.steps import build_decode_step, build_prefill_step
 
 #: leaves the transformer families' steps only use cast to bfloat16
-CAST = {"wq", "wk", "wv", "wo", "gate", "up", "down", "embedding", "lm_head"}
+CAST = {"wq", "wk", "wv", "wo", "gate", "up", "down", "embedding", "lm_head",
+        "wkv_a", "wkv_b", "w_gate", "w_up", "w_down", "s_gate", "s_up",
+        "s_down"}
 
 
+# tied, untied, latent attention with a leading dense layer and experts
 @pytest.fixture(scope="module",
-                params=["qwen2-0.5b", "h2o-danube-1.8b"])  # tied, untied
+                params=["qwen2-0.5b", "h2o-danube-1.8b", "moonlight-16b-a3b"])
 def model_params(request):
     model = build_model(get_config(request.param).reduced())
     return model, init_params(model.specs(), jax.random.PRNGKey(1))
@@ -42,6 +45,8 @@ def test_serving_tree_dtypes(model_params):
     kept = {"ln1", "ln2", "final_norm"}
     if model.cfg.qkv_bias:
         kept |= {"bq", "bk", "bv"}
+    if model.cfg.mla:
+        kept |= {"kv_norm", "router", "router_bias"}
     assert kept <= leaves.keys() and not kept & CAST
     assert ("lm_head" in leaves) == (not model.cfg.tie_embeddings)
 
@@ -80,6 +85,56 @@ def test_decode_same_on_serving_tree(model_params):
         np.testing.assert_array_equal(tok_b, tok_a)
         np.testing.assert_allclose(log_b, log_a, rtol=1e-6,
                                    atol=1e-6 * np.abs(log_a).max())
+
+
+def test_decode_converts_no_weight(model_params):
+    """The decode step on the serving tree casts no weight: its program
+    has no convert of a float32 value shaped as a cast weight (or one layer
+    of one)."""
+    model, params = model_params
+    serving = model.serving_params(params)
+    shapes = _cast_shapes(serving)
+    decode, _ = build_decode_step(model, batch=2, s_max=16)
+    cache = init_params(model.cache_specs(2, 16), jax.random.PRNGKey(0))
+    tok = jnp.zeros((2, 1), jnp.int32)
+    pos = jnp.zeros((2,), jnp.int32)
+    text = decode.lower(serving, cache, tok, pos).as_text()
+    assert not _weight_converts(text, shapes)
+
+
+def _cast_shapes(serving) -> set:
+    """Shapes of the serving tree's bfloat16 matrices, and of one layer of
+    each stacked one."""
+    out = set()
+    for leaf in jax.tree.leaves(serving):
+        if leaf.dtype == jnp.bfloat16:
+            out |= {leaf.shape} | ({leaf.shape[1:]} if leaf.ndim >= 3
+                                   else set())
+    return out
+
+
+def _weight_converts(text: str, shapes: set) -> set:
+    """Shapes among ``shapes`` of float32 values converted to bfloat16 in
+    a lowered program's text (StableHLO, as the step asks for it, before a
+    backend adds conversions of its own)."""
+    import re
+
+    found = re.findall(r"stablehlo\.convert %\S+ : \(tensor<([0-9x]+)xf32>\)"
+                       r" -> tensor<[0-9x]+xbf16>", text)
+    return {tuple(int(d) for d in dims.split("x")) for dims in found} & shapes
+
+
+def test_weight_converts_seen_on_the_float32_tree():
+    """The check above sees the casts where the step does them: the
+    same step on the float32 tree converts weight-shaped operands."""
+    model = build_model(get_config("moonlight-16b-a3b").reduced())
+    params = init_params(model.specs(), jax.random.PRNGKey(1))
+    shapes = _cast_shapes(model.serving_params(params))
+    decode, _ = build_decode_step(model, batch=2, s_max=16)
+    cache = init_params(model.cache_specs(2, 16), jax.random.PRNGKey(0))
+    text = decode.lower(params, cache, jnp.zeros((2, 1), jnp.int32),
+                        jnp.zeros((2,), jnp.int32)).as_text()
+    assert _weight_converts(text, shapes)
 
 
 @pytest.mark.parametrize("arch", ["rwkv6-1.6b", "zamba2-1.2b",

@@ -58,17 +58,36 @@ def test_schedules_shape():
 
 def test_grad_accumulation_matches_full_batch():
     def loss(p, batch):
-        return jnp.mean((batch["x"] @ p["w"] - batch["y"]) ** 2)
+        return jnp.mean((batch["x"] @ p["w"] - batch["y"]) ** 2), {}
 
     key = jax.random.PRNGKey(0)
     p = {"w": jax.random.normal(key, (8, 4))}
     batch = {"x": jax.random.normal(key, (16, 8)),
              "y": jax.random.normal(key, (16, 4))}
-    l1, g1 = jax.value_and_grad(loss)(p, batch)
-    l2, g2 = GradAccumulator(4).grads(loss, p, batch)
+    (l1, _), g1 = jax.value_and_grad(loss, has_aux=True)(p, batch)
+    l2, g2, _ = GradAccumulator(4).grads(loss, p, batch)
     assert float(jnp.abs(l1 - l2)) < 1e-5
     np.testing.assert_allclose(np.asarray(g1["w"]), np.asarray(g2["w"]),
                                rtol=1e-5, atol=1e-6)
+
+
+def test_grad_accumulation_sums_stats():
+    """The loss function's statistics come back summed over the
+    microbatches, beside the mean loss and gradients."""
+    def loss(p, batch):
+        err = batch["x"] @ p["w"] - batch["y"]
+        return jnp.mean(err ** 2), {"rows": jnp.float32(err.shape[0])}
+
+    key = jax.random.PRNGKey(0)
+    p = {"w": jax.random.normal(key, (8, 4))}
+    batch = {"x": jax.random.normal(key, (16, 8)),
+             "y": jax.random.normal(key, (16, 4))}
+    (l1, _), g1 = jax.value_and_grad(loss, has_aux=True)(p, batch)
+    l2, g2, stats = GradAccumulator(4).grads(loss, p, batch)
+    assert float(jnp.abs(l1 - l2)) < 1e-5
+    np.testing.assert_allclose(np.asarray(g1["w"]), np.asarray(g2["w"]),
+                               rtol=1e-5, atol=1e-6)
+    assert float(stats["rows"]) == 16.0
 
 
 def test_topk_compression_error_feedback():
